@@ -24,6 +24,9 @@ def main(argv=None) -> None:
              "op-level breakdown with TensorBoard's profile plugin",
     )
     args, _ = parser.parse_known_args(argv)
+    from repro.launch.cache import enable_compile_cache
+
+    enable_compile_cache()
 
     failures = []
     print("name,us_per_call,derived")

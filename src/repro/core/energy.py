@@ -20,8 +20,14 @@ from __future__ import annotations
 
 import dataclasses
 
+import jax
 import jax.numpy as jnp
 from jax import Array
+
+#: Precision of every cost contraction: float32 on every backend. A TPU's
+#: default rounds matmul operands to bf16, which moves GMSA's argmin on near
+#: ties (0.3% of the N=256 fleet's dispatch decisions on a v5e).
+HIGHEST = jax.lax.Precision.HIGHEST
 
 
 def manager_energy_cost(omega: Array, pue: Array, r: Array, p_it: Array) -> Array:
@@ -40,13 +46,13 @@ def manager_energy_cost(omega: Array, pue: Array, r: Array, p_it: Array) -> Arra
     """
     weighted = omega * pue                                # (N,)
     # einsum over the executor axis j; MXU-friendly batched matvec.
-    e = jnp.einsum("kij,j->ki", r, weighted)              # (K, N)
+    e = jnp.einsum("kij,j->ki", r, weighted, precision=HIGHEST)  # (K, N)
     return e * p_it[:, None]
 
 
 def manager_energy(pue: Array, r: Array, p_it: Array) -> Array:
     """Per-job *energy* (not cost): E[k, i] = P^k * sum_j PUE_j * r[k, i, j]."""
-    return jnp.einsum("kij,j->ki", r, pue) * p_it[:, None]
+    return jnp.einsum("kij,j->ki", r, pue, precision=HIGHEST) * p_it[:, None]
 
 
 def slot_cost(f: Array, arrivals: Array, e: Array) -> Array:

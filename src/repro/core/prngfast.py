@@ -13,8 +13,8 @@ Monte-Carlo trace builds (~25% of a simulated run).
 for the CPU platform — no custom math, just the other of jax's two
 lowerings, ~4x faster bit generation here. Called at ``repro`` import;
 set ``REPRO_ROLLED_THREEFRY=1`` to keep jax's default, and any failure to
-reach the (internal, version-pinned: jax 0.4.37 in CI) registration APIs
-degrades silently to that default.
+reach the (internal, version-pinned: jax 0.9.0) registration APIs degrades
+silently to that default.
 """
 
 from __future__ import annotations

@@ -49,7 +49,7 @@ import jax
 import jax.numpy as jnp
 from jax import Array
 
-from repro.core.energy import manager_energy, manager_energy_cost
+from repro.core.energy import HIGHEST
 from repro.core.queues import queue_step
 from repro.telemetry.config import TelemetryConfig
 from repro.telemetry.config import enabled as _tel_enabled
@@ -103,11 +103,11 @@ def energy_tables(
     ``wpue`` / ``pue`` are (T, N).
     """
     if r.ndim == 4:
-        e_cost = jnp.einsum("tkij,tj->tki", r, wpue)
-        e_raw = jnp.einsum("tkij,tj->tki", r, pue)
+        e_cost = jnp.einsum("tkij,tj->tki", r, wpue, precision=HIGHEST)
+        e_raw = jnp.einsum("tkij,tj->tki", r, pue, precision=HIGHEST)
     else:
-        e_cost = jnp.einsum("kij,tj->tki", r, wpue)
-        e_raw = jnp.einsum("kij,tj->tki", r, pue)
+        e_cost = jnp.einsum("kij,tj->tki", r, wpue, precision=HIGHEST)
+        e_raw = jnp.einsum("kij,tj->tki", r, pue, precision=HIGHEST)
     return e_cost * p_it[None, :, None], e_raw * p_it[None, :, None]
 
 
@@ -121,8 +121,8 @@ def energy_row(
     off-schedule recovery epochs invalidate the precomputed epoch tables,
     and re-derive each remaining slot's row from the carried ``r``.
     """
-    e_cost = jnp.einsum("kij,j->ki", r, wpue_t)
-    e_raw = jnp.einsum("kij,j->ki", r, pue_t)
+    e_cost = jnp.einsum("kij,j->ki", r, wpue_t, precision=HIGHEST)
+    e_raw = jnp.einsum("kij,j->ki", r, pue_t, precision=HIGHEST)
     return e_cost * p_it[:, None], e_raw * p_it[:, None]
 
 

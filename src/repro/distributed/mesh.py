@@ -2,7 +2,7 @@
 
 Every ``*_many`` / ``sweep_*`` entry point replicates one simulation over a
 (n_runs,) axis of PRNG keys. This module maps that axis across devices with
-``shard_map`` (via the 0.4.x/0.5.x shim in :mod:`repro.distributed.compat`):
+``jax.shard_map``:
 
 * :func:`ensure_host_devices` — the ``XLA_FLAGS`` bootstrap idiom
   (``--xla_force_host_platform_device_count=8``): one process, eight CPU
@@ -16,8 +16,9 @@ Determinism contract: the (n_runs,) key array is computed exactly as in the
 single-device path (one ``jax.random.split`` at the entry point) and then
 merely *laid out* across devices — no per-device folding enters the key
 stream, and each run's trace build + simulation is elementwise in the runs
-axis. Sharded outputs are therefore bitwise-identical to the single-device
-vmap at every device count (pinned by ``tests/test_sharded.py``).
+axis. Sharded outputs therefore match the single-device vmap per run at
+every device count (pinned by ``tests/test_sharded.py``): bitwise, except
+where XLA rounds a float sum differently at a different per-device batch.
 
 Non-divisible ``n_runs`` pads the key axis by repeating the leading keys up
 to a device multiple and slices the padding back off, so downstream
@@ -36,8 +37,6 @@ import jax
 import jax.numpy as jnp
 from jax import Array
 from jax.sharding import Mesh, PartitionSpec as P
-
-from repro.distributed.compat import shard_map
 
 __all__ = [
     "RUNS_AXIS",
@@ -58,18 +57,6 @@ def host_platform_flag(n_devices: int) -> str:
     return f"{_FLAG}={int(n_devices)}"
 
 
-def _backends_initialized() -> bool:
-    """Whether jax has already materialized its backends (flag too late)."""
-    try:
-        from jax._src import xla_bridge
-
-        if hasattr(xla_bridge, "backends_are_initialized"):
-            return bool(xla_bridge.backends_are_initialized())
-        return bool(getattr(xla_bridge, "_backends", {}))
-    except Exception:  # pragma: no cover - private-API drift
-        return True  # can't tell: assume live, forcing the loud path
-
-
 def ensure_host_devices(n_devices: int) -> int:
     """Request ``n_devices`` host CPU devices; must run before backend init.
 
@@ -86,7 +73,9 @@ def ensure_host_devices(n_devices: int) -> int:
     n_devices = int(n_devices)
     if n_devices < 1:
         raise ValueError(f"n_devices must be >= 1, got {n_devices}")
-    if _backends_initialized():
+    from jax._src import xla_bridge
+
+    if xla_bridge.backends_are_initialized():
         have = jax.device_count()
         if have >= n_devices:
             return have
@@ -146,7 +135,7 @@ def sharded_runs(
     pad = (-n_runs) % n_dev
     if pad:
         keys = jnp.concatenate([keys, keys[:pad]], axis=0)
-    body = shard_map(
+    body = jax.shard_map(
         lambda ks: jax.vmap(one)(ks),
         mesh=mesh,
         in_specs=P(RUNS_AXIS),

@@ -44,9 +44,12 @@ def _kernel(q_ref, mu_ref, a_ref, vp_ref, wpue_ref, r_ref,
 
     # Cost matvec on the MXU: (K_T*N_T, J_T) @ (J_T, 1).
     r_tile = r_ref[...].reshape(K_T * N_T, J_T)
+    # HIGHEST: full-f32 MXU passes, the oracle's semantics (the default
+    # rounds operands to bf16, which moves argmins near ties).
     partial = jax.lax.dot_general(
         r_tile, wpue_ref[...],                      # (J_T, 1)
         (((1,), (0,)), ((), ())),
+        precision=jax.lax.Precision.HIGHEST,
         preferred_element_type=jnp.float32,
     ).reshape(K_T, N_T)
     acc_ref[...] += partial

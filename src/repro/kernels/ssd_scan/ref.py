@@ -26,13 +26,17 @@ def ssd_scan_ref(
     bsz, s, h, p = x.shape
     n = b_mat.shape[-1]
 
+    hi = jax.lax.Precision.HIGHEST     # float32 contractions on a TPU too
+
     def step(state, t_in):
         xt, dtt, bt, ct = t_in
         decay = jnp.exp(dtt.astype(jnp.float32) * a.astype(jnp.float32))  # (B,H)
         upd = jnp.einsum("bh,bhp,bn->bhpn", dtt.astype(jnp.float32),
-                         xt.astype(jnp.float32), bt.astype(jnp.float32))
+                         xt.astype(jnp.float32), bt.astype(jnp.float32),
+                         precision=hi)
         state = decay[:, :, None, None] * state + upd
-        y = jnp.einsum("bhpn,bn->bhp", state, ct.astype(jnp.float32))
+        y = jnp.einsum("bhpn,bn->bhp", state, ct.astype(jnp.float32),
+                       precision=hi)
         return state, y
 
     h0 = jnp.zeros((bsz, h, p, n), jnp.float32)

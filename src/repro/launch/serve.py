@@ -2,10 +2,10 @@
 
   PYTHONPATH=src python -m repro.launch.serve --slots 24 --v 1.0 \
       [--classes qwen2-0.5b,granite-3-2b] [--no-exec] [--pods 8] \
-      [--admit-max 6] [--kill "2:12"] [--dispatch kernel]
+      [--admit-max 6] [--kill "2:12"] [--dispatch kernel] [--variant full]
 
-Each request class is an architecture (smoke variant on this container)
-modeled as a 2-stage prefill→decode chain; prefill routes through the
+Each request class is an architecture (executed at its smoke variant
+unless ``--variant full``) modeled as a 2-stage prefill→decode chain; prefill routes through the
 placement layer's replica-read assignment over a drawn dataset layout,
 every slot dispatches through the joint stage scheduler (or the Pallas
 kernel path with ``--dispatch kernel``), and drained jobs actually
@@ -22,6 +22,7 @@ import numpy as np
 
 from repro.configs import get_arch
 from repro.core.iridium import build_task_allocation
+from repro.launch.cache import enable_compile_cache
 from repro.serve.engine import FleetConfig, FleetEngine, RequestClass
 from repro.telemetry.config import TelemetryConfig
 from repro.traces.bandwidth import bandwidth_draw
@@ -37,7 +38,10 @@ def build_engine(classes: list[str], slots: int, v: float, seed: int = 0,
                  telemetry: TelemetryConfig | None = None,
                  health: np.ndarray | None = None,
                  link_health: np.ndarray | None = None,
-                 hedge: float | None = None) -> FleetEngine:
+                 hedge: float | None = None,
+                 variant: str = "smoke") -> FleetEngine:
+    """Build a serving engine; ``variant`` is the executed model size
+    (``"smoke"`` or ``"full"``). Energy is always priced at full size."""
     key = jax.random.key(seed)
     k1, k2, k3, k4 = jax.random.split(key, 4)
     # Pods beyond the four Facebook DCs reuse their site climates (cycled).
@@ -46,7 +50,7 @@ def build_engine(classes: list[str], slots: int, v: float, seed: int = 0,
     omega = np.asarray(price_trace(k1, slots, 5.0, sites))
     pue = np.asarray(pue_trace(k2, slots, 5.0, sites))
     rcs = [
-        RequestClass(name=a, cfg=get_arch(a, "smoke"),
+        RequestClass(name=a, cfg=get_arch(a, variant),
                      energy_cfg=get_arch(a, "full"), arrival_rate=arrival)
         for a in classes
     ]
@@ -90,7 +94,10 @@ def main(argv=None):
     ap.add_argument("--no-exec", action="store_true",
                     help="skip real model execution (dispatch-only)")
     ap.add_argument("--seed", type=int, default=0)
+    ap.add_argument("--variant", choices=["smoke", "full"], default="smoke",
+                    help="model size the pods execute")
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     alive = None
     if args.kill:
@@ -106,7 +113,7 @@ def main(argv=None):
     engine = build_engine(
         args.classes.split(","), args.slots, args.v, args.seed, args.arrival,
         n_pods=args.pods, admit_max=args.admit_max, dispatch=args.dispatch,
-        alive=alive, health=health, hedge=args.hedge,
+        alive=alive, health=health, hedge=args.hedge, variant=args.variant,
     )
     out = engine.run(execute_real=not args.no_exec)
     print(f"slots={args.slots} classes={args.classes} pods={args.pods} "

@@ -131,12 +131,7 @@ def moe_ffn(
     """
     cap = expert_capacity(x.shape[1], cfg, capacity_factor)
 
-    # Deferred: importing repro.distributed at module scope is circular
-    # (distributed/__init__ -> sharding -> models.lm -> this module).
-    from repro.distributed.compat import get_abstract_mesh
-    from repro.distributed.compat import shard_map as _shard_map
-
-    mesh = get_abstract_mesh()
+    mesh = jax.sharding.get_abstract_mesh()
     f = cfg.moe_d_ff or cfg.d_ff
     batch_axes = tuple(
         a for a in ("pod", "data")
@@ -158,7 +153,7 @@ def moe_ffn(
         y, aux = _moe_local(xl, wr, wg, wu, wd, cfg, cap, psum_axis="model")
         return y, jax.lax.pmean(aux, batch_axes)
 
-    return _shard_map(
+    return jax.shard_map(
         local_fn,
         in_specs=(
             P(bspec),                      # x: rows local per batch shard

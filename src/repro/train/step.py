@@ -27,7 +27,6 @@ import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.configs.base import ModelConfig
-from repro.distributed.compat import shard_map
 from repro.distributed.compression import sync_tree
 from repro.distributed.sharding import batch_pspecs, param_pspecs
 from repro.models.lm import loss_fn
@@ -104,7 +103,7 @@ def make_train_step(cfg: ModelConfig, mesh: Mesh, tcfg: TrainStepConfig):
                 return loss, metrics, grads, resid
 
             pspec_rep = jax.tree.map(lambda _: P(), params)
-            loss, metrics, grads, resid = shard_map(
+            loss, metrics, grads, resid = jax.shard_map(
                 pod_local,
                 mesh=mesh,
                 in_specs=(

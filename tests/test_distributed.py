@@ -61,11 +61,11 @@ _SUBPROCESS_PROG = textwrap.dedent("""
     from repro.train.step import TrainStepConfig, make_train_step
     from repro.train.optimizer import adamw_init
     from repro.serve.step import make_decode_step
+    from repro.launch.mesh import make_debug_mesh
 
-    mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
-    # jax >= 0.5 spells the mesh context jax.set_mesh; on older versions the
-    # Mesh object itself is the context manager.
-    mesh_ctx = (lambda m: jax.set_mesh(m)) if hasattr(jax, "set_mesh") else (lambda m: m)
+    # The launcher's (2, 2, 2) mesh: Auto axes, placed by in/out shardings.
+    mesh = make_debug_mesh(multi_pod=True)
+    mesh_ctx = jax.set_mesh
     cfg = C.get_arch("qwen2-0.5b", "smoke")
     shape = ShapeConfig("t", "train", 64, 8)
     out = {}
@@ -128,11 +128,11 @@ _MOE_MESH_PROG = textwrap.dedent("""
     import json
     import jax, jax.numpy as jnp
     import repro.configs as C
-    from repro.distributed.compat import get_abstract_mesh
+    from jax.sharding import get_abstract_mesh
     from repro.models.moe import moe_ffn, _moe_local, expert_capacity
 
     mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
-    mesh_ctx = (lambda m: jax.set_mesh(m)) if hasattr(jax, "set_mesh") else (lambda m: m)
+    mesh_ctx = jax.set_mesh
     cfg = C.get_arch("deepseek-moe-16b", "smoke")
     d, e = cfg.d_model, cfg.num_experts
     f = cfg.moe_d_ff or cfg.d_ff
@@ -157,9 +157,9 @@ _MOE_MESH_PROG = textwrap.dedent("""
 
 @pytest.mark.slow
 def test_moe_manual_shard_map_path_live_subprocess():
-    """The ambient-mesh compat shim must expose the mesh on every jax version,
-    so moe_ffn's manual shard_map path (not the replicating fallback) runs —
-    and agrees with the single-device reference."""
+    """The ambient mesh must be visible to moe_ffn, so its manual shard_map
+    path (not the replicating fallback) runs — and agrees with the
+    single-device reference."""
     env = dict(os.environ)
     env.pop("XLA_FLAGS", None)
     res = subprocess.run(
@@ -169,7 +169,7 @@ def test_moe_manual_shard_map_path_live_subprocess():
     )
     assert res.returncode == 0, res.stderr[-2000:]
     report = json.loads(res.stdout.strip().splitlines()[-1])
-    assert report["ambient"], "compat.get_abstract_mesh missed the ambient mesh"
+    assert report["ambient"], "get_abstract_mesh missed the ambient mesh"
     assert report["dy"] < 1e-5
     assert report["daux"] < 1e-6
 

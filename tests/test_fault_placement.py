@@ -69,8 +69,14 @@ def _pcfg(cfg, **kw):
 ])
 @pytest.mark.parametrize("rule_name", ["static", "adaptive"])
 def test_all_alive_mask_bit_exact(paper_setup, policy, rule_name):
-    """alive=ones reproduces the no-fault outputs bit for bit — every
-    masking op in the fault path is an exact identity or an edge select."""
+    """alive=ones reproduces the no-fault outputs — every masking op in the
+    fault path is an exact identity or an edge select, so dispatch,
+    placements and the fault bills match bit for bit.
+
+    The two calls compile to differently fused programs, and XLA:CPU
+    (jax 0.9) rounds their float sums differently: backlogs may differ at
+    ULP level (at most 5.2e-7 relative was seen), so float series compare
+    to 2e-6 relative."""
     cfg, template, _, up, down = paper_setup
     rule = (static_placement_rule if rule_name == "static"
             else make_adaptive_rule(up))
@@ -81,10 +87,13 @@ def test_all_alive_mask_bit_exact(paper_setup, policy, rule_name):
     o1 = simulate_placed(template, up, down, policy, rule, key, pcfg,
                          alive=ones)
     for field in o0._fields:
-        np.testing.assert_array_equal(
-            np.asarray(getattr(o0, field)), np.asarray(getattr(o1, field)),
-            err_msg=field,
-        )
+        a, b = np.asarray(getattr(o0, field)), np.asarray(getattr(o1, field))
+        if field in ("f_trace", "placements", "recovery_cost",
+                     "recovery_gb"):
+            np.testing.assert_array_equal(a, b, err_msg=field)
+        else:
+            np.testing.assert_allclose(a, b, rtol=2e-6, atol=1e-6,
+                                       err_msg=field)
     assert float(o1.recovery_cost.sum()) == 0.0
     assert float(o1.recovery_gb.sum()) == 0.0
 

@@ -120,22 +120,22 @@ def test_stage_trace_generators_shapes():
 ], ids=["gmsa", "data", "random", "jsq", "greedy"])
 def test_single_stage_bit_exact(paper_setup, policy):
     """A trivial one-stage dag (selectivity 1, no shuffle) reproduces
-    `simulate`'s cost/backlog/dispatch bit for bit, on every policy."""
+    `simulate`'s dispatch bit for bit, on every policy, and its cost and
+    backlog series.
+
+    The two engines are differently fused programs; XLA:CPU (jax 0.9)
+    rounds their float sums differently, so the series may differ at ULP
+    level (1.3e-7 relative was seen) and compare to 2e-6 relative."""
     cfg, template, _, wan = paper_setup
     dag = single_stage_dag(cfg.k_types)
     key = jax.random.key(3)
     o_s = simulate(template, policy, key)
     o_j = simulate_staged(template, dag, wan, policy, key)
-    np.testing.assert_array_equal(np.asarray(o_s.cost), np.asarray(o_j.cost))
-    np.testing.assert_array_equal(
-        np.asarray(o_s.energy), np.asarray(o_j.energy)
-    )
-    np.testing.assert_array_equal(
-        np.asarray(o_s.backlog_total), np.asarray(o_j.backlog_total)
-    )
-    np.testing.assert_array_equal(
-        np.asarray(o_s.backlog_avg), np.asarray(o_j.backlog_avg)
-    )
+    for field in ("cost", "energy", "backlog_total", "backlog_avg"):
+        np.testing.assert_allclose(
+            np.asarray(getattr(o_s, field)), np.asarray(getattr(o_j, field)),
+            rtol=2e-6, atol=1e-6, err_msg=field,
+        )
     np.testing.assert_array_equal(
         np.asarray(o_s.f_trace), np.asarray(o_j.f_trace[..., 0])
     )

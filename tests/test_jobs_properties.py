@@ -75,16 +75,22 @@ def test_prop_stage_flow_conservation(seed, n, k, s):
 @given(seed=st.integers(0, 2**31 - 1), n=st.integers(2, 4),
        k=st.integers(1, 3))
 def test_prop_single_stage_bit_exact(seed, n, k):
-    """Random single-stage scenarios are bit-exact with `simulate`."""
+    """Random single-stage scenarios match `simulate` (to 2e-6 relative:
+    the engines are differently fused programs, see test_jobs.py)."""
     inputs, _, wan = _random_case(seed, n, k, s=1, t=12)
     dag = single_stage_dag(k)
     key = jax.random.key(seed % 997)
     pol = dispatch_fn(2.0)
     o_s = simulate(inputs, pol, key)
     o_j = simulate_staged(inputs, dag, wan, pol, key)
-    np.testing.assert_array_equal(np.asarray(o_s.cost), np.asarray(o_j.cost))
     np.testing.assert_array_equal(
-        np.asarray(o_s.q_final), np.asarray(o_j.q_final[..., 0])
+        np.asarray(o_s.f_trace), np.asarray(o_j.f_trace[..., 0])
+    )
+    np.testing.assert_allclose(np.asarray(o_s.cost), np.asarray(o_j.cost),
+                               rtol=2e-6, atol=1e-6)
+    np.testing.assert_allclose(
+        np.asarray(o_s.q_final), np.asarray(o_j.q_final[..., 0]),
+        rtol=2e-6, atol=1e-6,
     )
     assert float(o_j.wan_cost.sum()) == 0.0
 

@@ -61,7 +61,11 @@ def test_dispatch_replays_simulate_staged(engine, out):
     np.testing.assert_allclose(
         out["cost"], np.asarray(outs.cost), rtol=1e-5, atol=1e-12
     )
-    np.testing.assert_array_equal(out["wan_cost"], np.asarray(outs.wan_cost))
+    # The serving loop and the scan are differently fused programs: the
+    # KV-handoff bill may differ at ULP level (1.1e-7 relative was seen).
+    np.testing.assert_allclose(
+        out["wan_cost"], np.asarray(outs.wan_cost), rtol=1e-6, atol=0.0
+    )
     sim_total = float(
         np.asarray(outs.cost).sum() + np.asarray(outs.wan_cost).sum()
     )

@@ -3,10 +3,13 @@
 Three contracts pinned here:
 
 * **Device-count invariance** — every ``*_many`` / ``sweep_*`` entry point
-  produces bitwise-identical outputs sharded over a runs mesh vs the
-  single-device vmap, at every device count. The same split keys are
-  merely laid out across devices, so this holds exactly, not just in
-  distribution. In-process tests run on whatever devices the process has
+  produces the same outputs sharded over a runs mesh as the single-device
+  vmap, at every device count. The same split keys are merely laid out
+  across devices, so this holds per run, not just in distribution: bitwise
+  for every engine but ``simulate_placed_many``, whose per-slot cost and
+  energy sums XLA:CPU (jax 0.9) rounds differently at a different per-device
+  batch (ULP level, 1.7e-7 relative seen; dispatch and placements stay
+  bitwise). In-process tests run on whatever devices the process has
   (1 in tier-1; 8 in the CI multi-device job); the subprocess test forces
   an 8-way CPU pod regardless, including the ``n_runs=1000`` case and a
   non-divisible ``n_runs`` exercising pad-and-mask.
@@ -376,7 +379,13 @@ _INVARIANCE_PROG = textwrap.dedent("""
                               pcfg, alive=alive)
     pb = simulate_placed_many(build, up, down, gmsa_policy, rule, key, 12,
                               pcfg, alive=alive, mesh=mesh)
-    report["simulate_placed_many"] = eq(pa, pb)
+    bills = ("cost", "energy")
+    report["simulate_placed_many"] = eq(
+        pa._replace(**{f: None for f in bills}),
+        pb._replace(**{f: None for f in bills}))
+    report["simulate_placed_many_bills"] = all(
+        bool(jnp.allclose(getattr(pa, f), getattr(pb, f), rtol=1e-6, atol=0))
+        for f in bills)
     print(json.dumps(report))
 """)
 
@@ -399,6 +408,7 @@ def test_eight_device_invariance_subprocess():
     assert report["sweep_grid"]
     assert report["simulate_staged_many"]
     assert report["simulate_placed_many"]
+    assert report["simulate_placed_many_bills"]
 
 
 # ---------------------------------------------------------------------------
