@@ -1,0 +1,11 @@
+"""dispatch_ms: device ms per call under the bench_dispatch scope (the policy's GMSA decision); self time,
+averaged over the chips."""
+
+from trace_reduce import layer_of
+
+
+def read(trace, cell):
+    s = trace.self_seconds(lambda scope: layer_of(scope) == "dispatch")
+    if s <= 0 or not cell["calls"]:
+        return None
+    return s * 1e3 / cell["calls"]

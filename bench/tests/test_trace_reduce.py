@@ -1,0 +1,109 @@
+"""The reduction from a device trace to per-layer metrics, on small traces
+recorded on a TPU v5e by the harness (``bench/tests/data``)."""
+
+import importlib.util
+from pathlib import Path
+
+import pytest
+from conftest import BENCH
+
+from trace_reduce import Op, _set_nesting, layer_of, reduce_trace
+
+DATA = Path(__file__).resolve().parent / "data"
+TRACES = sorted(DATA.glob("*.trace.json.gz"))
+LAYERS = {"trace generation", "dispatch", "placement rule", "scan engines",
+          "outside the scans"}
+
+
+def reader(name):
+    spec = importlib.util.spec_from_file_location(name, BENCH / "metrics" / f"{name}.py")
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
+def cell_ctx(path, calls):
+    fleet = path.name.startswith("fleet")
+    return {"calls": calls, "n_runs": 100 if fleet else 1000, "chips": 1,
+            "t_slots": 288, "n_sites": 256 if fleet else 4, "k_types": 8 if fleet else 1,
+            "peaks": {"hbm_bytes_per_s": 819e9, "bf16_flops_per_s": 197e12}}
+
+
+def test_fixtures_are_present():
+    assert TRACES, f"no recorded traces under {DATA}"
+
+
+@pytest.mark.parametrize("path", TRACES, ids=lambda p: p.name)
+def test_a_recorded_trace_reduces_to_busy_time_inside_its_window(path):
+    tr = reduce_trace(path)
+    assert tr.platform == "tpu" and tr.n_devices == 1
+    assert tr.window_s > 0
+    assert 0 < tr.busy_s[0] <= tr.window_s * 1.001
+
+
+@pytest.mark.parametrize("path", TRACES, ids=lambda p: p.name)
+def test_self_times_add_up_to_the_busy_time(path):
+    tr = reduce_trace(path)
+    total = sum(op.self_us for _, op in tr.ops) * 1e-6
+    assert total == pytest.approx(tr.busy_s[0], rel=1e-3)
+
+
+@pytest.mark.parametrize("path", TRACES, ids=lambda p: p.name)
+def test_every_op_lands_in_a_known_layer_and_trace_generation_leads(path):
+    tr = reduce_trace(path)
+    layers = {layer_of(op.scope) for _, op in tr.ops}
+    assert layers <= LAYERS
+    gen = tr.self_seconds(lambda s: layer_of(s) == "trace generation")
+    assert gen > 0.5 * tr.busy_s[0]
+
+
+def test_the_kernel_is_counted_in_the_dispatch_layer():
+    path = DATA / "fleet256_kernel_mc.trace.json.gz"
+    tr = reduce_trace(path)
+    kernels = [op for _, op in tr.ops if op.name.startswith("gmsa_score")]
+    assert kernels and all(layer_of(op.scope) == "dispatch" for op in kernels)
+
+
+@pytest.mark.parametrize("path", TRACES, ids=lambda p: p.name)
+def test_readers_read_what_is_there_and_nothing_else(path):
+    tr = reduce_trace(path)
+    ctx = cell_ctx(path, calls=2)
+    assert reader("placement_rule_ms").read(tr, ctx) is None
+    idle = reader("device_idle_pct").read(tr, ctx)
+    assert 0 <= idle < 100
+    for name in ("tracegen_ms", "scan_body_ms", "dispatch_ms"):
+        assert reader(name).read(tr, ctx) > 0
+    share = reader("dispatch_roofline").read(tr, ctx)
+    assert 0 < share <= 100
+
+
+@pytest.mark.parametrize("path", TRACES, ids=lambda p: p.name)
+def test_breakdown_names_at_most_ten_ops_and_gaps(path):
+    b = reduce_trace(path).breakdown(layer_of)
+    for key in ("device_ops", "idle_gaps"):
+        assert len(b[key]) <= 10
+        assert all(isinstance(n, str) and s > 0 for n, s in b[key])
+
+
+def test_the_least_dispatch_time_is_bound_by_bytes():
+    mod = reader("dispatch_roofline")
+    peaks = {"hbm_bytes_per_s": 819e9, "bf16_flops_per_s": 197e12}
+    secs, bound = mod.least_seconds(100, 288, 256, 8, peaks)
+    assert bound == "bytes"
+    per_slot = 100 * 4 * (2 * 2048 + 8 + 8) + 4 * (2048 + 8)
+    assert secs == pytest.approx(288 * per_slot / 819e9)
+    _, ops = mod.least_work(100, 288, 256, 8)
+    assert ops / 197e12 < secs / 100
+
+
+def test_a_loop_counts_only_its_own_time_and_takes_its_body_scope():
+    body = "jit(f)/while/body/"
+    ops = [Op(0.0, 10.0, "while.1", "", "while"),
+           Op(1.0, 3.0, "fusion.1", body + "bench_dispatch/add:", "loop fusion"),
+           Op(5.0, 4.0, "fusion.2", body + "add:", "loop fusion"),
+           Op(20.0, 2.0, "copy.1", "jit(f)/copy:", "copy")]
+    _set_nesting(ops)
+    self_us = {op.name: op.self_us for op in ops}
+    assert self_us == {"while.1": 3.0, "fusion.1": 3.0, "fusion.2": 4.0, "copy.1": 2.0}
+    assert ops[0].scope == body
+    assert layer_of(ops[0].scope) == "scan engines"
