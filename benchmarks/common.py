@@ -133,8 +133,7 @@ def read_bench_history(path=None) -> list[dict]:
     """Load the perf-trajectory entries (``[]`` on missing/corrupt file).
 
     Shared by :func:`write_bench_json` (append + dedup) and callers that
-    want to inspect the trajectory (e.g. before handing it to
-    ``repro.telemetry.bench_check``).
+    want to inspect the trajectory.
     """
     path = pathlib.Path(path) if path is not None else BENCH_JSON
     if not path.exists():

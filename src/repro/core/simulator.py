@@ -56,6 +56,7 @@ from repro.telemetry.config import enabled as _tel_enabled
 from repro.telemetry.config import histograms as _tel_hist
 from repro.telemetry.metrics import hist_series
 from repro.telemetry.ring import TelemetryFrame, ring_init
+from repro.telemetry.scopes import GMSA_DECIDE, GMSA_SCAN, MC_DRAWS
 
 
 class SimInputs(NamedTuple):
@@ -223,7 +224,8 @@ def simulate(
                 aux = (aux, w)
             if wants_r:
                 aux = aux + (rr,)
-            return policy(kk, q0, a, m, e, aux, scalar)
+            with jax.named_scope(GMSA_DECIDE):
+                return policy(kk, q0, a, m, e, aux, scalar)
 
         f_all = jax.vmap(
             call,
@@ -260,7 +262,8 @@ def simulate(
                 key, sub = jax.random.split(key)
             else:
                 sub = key0
-            f = policy(sub, q, arrivals, mu, e_cost, aux, scalar)
+            with jax.named_scope(GMSA_DECIDE):
+                f = policy(sub, q, arrivals, mu, e_cost, aux, scalar)
         else:
             arrivals, mu, e_cost, e_raw, f = xs
         q_next, out = slot_step(q, f, arrivals, mu, e_cost, e_raw)
@@ -278,7 +281,8 @@ def simulate(
     if wants_r and r_varying:
         xs = xs + (inputs.r,)
     carry0 = (q0, key) if keyed else q0
-    final_carry, scan_outs = jax.lax.scan(slot, carry0, xs)
+    with jax.named_scope(GMSA_SCAN):
+        final_carry, scan_outs = jax.lax.scan(slot, carry0, xs)
     if tel_on:
         (cost, energy, btot, bavg, f_trace, q_site) = scan_outs
     else:
@@ -334,8 +338,9 @@ def simulate_many(
 
     def one(run_key):
         k_build, k_sim = jax.random.split(run_key)
-        return simulate(build_inputs(k_build), policy, k_sim, scalar,
-                        telemetry, health)
+        with jax.named_scope(MC_DRAWS):
+            inputs = build_inputs(k_build)
+        return simulate(inputs, policy, k_sim, scalar, telemetry, health)
 
     if mesh is None:
         return jax.vmap(one)(keys)

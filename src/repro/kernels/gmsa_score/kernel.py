@@ -26,6 +26,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.telemetry.scopes import GMSA_SCORE
+
 K_T = 8      # job-type tile (sublane-aligned)
 N_T = 128    # manager tile (lane-aligned)
 J_T = 128    # executor tile (matvec contraction)
@@ -113,4 +115,5 @@ def gmsa_score_kernel(q, mu, a, vp, wpue, r, *, interpret: bool = False):
             pltpu.VMEM((K_T, 1), jnp.int32),       # running argmin
         ],
         interpret=interpret,
+        name=GMSA_SCORE,
     )(q, mu, a, vp, wpue, r)

@@ -32,6 +32,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.telemetry.scopes import SSD_SCAN
+
 _HI = jax.lax.Precision.HIGHEST
 
 
@@ -123,4 +125,5 @@ def ssd_scan_kernel(x, dt, adt, b_mat, c_mat, *, chunk: int,
         ],
         scratch_shapes=[pltpu.VMEM((p, n), jnp.float32)],
         interpret=interpret,
+        name=SSD_SCAN,
     )(x, dt, adt, b_mat, c_mat)

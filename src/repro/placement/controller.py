@@ -72,6 +72,14 @@ from repro.telemetry.ring import (
     ring_init,
     ring_push,
 )
+from repro.telemetry.scopes import (
+    GMSA_DECIDE,
+    GMSA_SCAN,
+    MC_DRAWS,
+    PLACED_EPOCHS,
+    PLACED_RECOVERY,
+    PLACED_RULE,
+)
 from repro.traces.datasets import io_slowdown_from_bandwidth
 from repro.placement.wan import (
     DEFAULT_ENERGY_PER_GB,
@@ -508,7 +516,8 @@ def simulate_placed(
             q=q, sizes_gb=size_e, capacity_gb=cap,
             alive=alive_b if faulty else None,
         )
-        target = rule(d_drift, obs)
+        with jax.named_scope(PLACED_RULE):
+            target = rule(d_drift, obs)
         if faulty:
             # The controller enforces survivor-only targets regardless of
             # whether the plugged-in rule is survivor-aware; with regions
@@ -633,6 +642,7 @@ def simulate_placed(
                 # slot body stays the base engine's few fused ops. The
                 # predicate depends only on the (unbatched) alive trace,
                 # so the cond survives the Monte-Carlo vmap as a cond.
+                @jax.named_scope(PLACED_RECOVERY)
                 def recover(q_r, d_masked_r, d_drop_r, mu_r):
                     obs_r = SlowObs(
                         wpue_bar=wpue_t, mu_bar=mu_r, q=q_r,
@@ -640,9 +650,10 @@ def simulate_placed(
                     )
                     surv_t = (alive_t if regions is None
                               else region_averse_weights(alive_t, regions))
+                    with jax.named_scope(PLACED_RULE):
+                        tgt_r = rule(d_drop_r, obs_r)
                     tgt = _survivor_renorm(
-                        rule(d_drop_r, obs_r) * surv_t[None, :],
-                        d_drop_r, axis=1,
+                        tgt_r * surv_t[None, :], d_drop_r, axis=1,
                     )
                     d_rec = d_drop_r + mb * (tgt - d_drop_r)
                     d_rec = d_rec / jnp.maximum(
@@ -743,7 +754,8 @@ def simulate_placed(
                 aux = (aux, wpue_t)
             if wants_r:
                 aux = aux + ((r_c if faulty else r_e),)
-            f = policy(sub, q2, arrivals, mu, ec, aux, scalar)
+            with jax.named_scope(GMSA_DECIDE):
+                f = policy(sub, q2, arrivals, mu, ec, aux, scalar)
             if faulty:
                 # No dispatch mass to dead sites, whatever the policy says.
                 n_alive = jnp.maximum(jnp.sum(alive_t), 1.0)
@@ -786,15 +798,14 @@ def simulate_placed(
             carry0 = (q, key, d_new, r_e, jnp.bool_(False))
             if tel_trace:
                 carry0 = carry0 + (ring,)
-                (q, key, d_carry, _, _, ring), slot_outs = jax.lax.scan(
-                    slot, carry0, slot_xs
-                )
-            else:
-                (q, key, d_carry, _, _), slot_outs = jax.lax.scan(
-                    slot, carry0, slot_xs
-                )
+            with jax.named_scope(GMSA_SCAN):
+                carry_out, slot_outs = jax.lax.scan(slot, carry0, slot_xs)
+            q, key, d_carry = carry_out[:3]
+            if tel_trace:
+                ring = carry_out[-1]
         else:
-            (q, key), slot_outs = jax.lax.scan(slot, (q, key), slot_xs)
+            with jax.named_scope(GMSA_SCAN):
+                (q, key), slot_outs = jax.lax.scan(slot, (q, key), slot_xs)
             d_carry = d_new
         epoch_out = slot_outs + (d_new, r_e, wan_c, wan_e, wan_gb, wan_lat,
                                  sync_c, scale_e)
@@ -817,9 +828,11 @@ def simulate_placed(
         xs = xs + (jnp.arange(n_epochs, dtype=jnp.int32),
                    jnp.arange(t_slots, dtype=jnp.int32).reshape(n_epochs, w))
         carry_init = carry_init + (ring_init(telemetry.capacity),)
-        (q_final, _, _, ring_out), outs = jax.lax.scan(epoch, carry_init, xs)
-    else:
-        (q_final, _, _), outs = jax.lax.scan(epoch, carry_init, xs)
+    with jax.named_scope(PLACED_EPOCHS):
+        carry_final, outs = jax.lax.scan(epoch, carry_init, xs)
+    q_final = carry_final[0]
+    if tel_trace:
+        ring_out = carry_final[-1]
     # Per-slot scan columns lead; the epoch-level audit trail follows.
     n_slot_cols = (5 + (2 if faulty else 0) + (1 if tel_on else 0)
                    + (1 if tel_hist else 0))
@@ -899,8 +912,10 @@ def simulate_placed_many(
 
     def one(run_key):
         k_build, k_sim = jax.random.split(run_key)
+        with jax.named_scope(MC_DRAWS):
+            inputs = build_inputs(k_build)
         return simulate_placed(
-            build_inputs(k_build), up, down, policy, rule, k_sim, cfg,
+            inputs, up, down, policy, rule, k_sim, cfg,
             scalar=scalar, ingest=ingest, sizes_gb=sizes_gb, alive=alive,
             move_budget=move_budget, telemetry=telemetry, health=health,
             link_health=link_health, regions=regions,

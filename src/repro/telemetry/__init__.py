@@ -22,9 +22,11 @@ Three layers:
   (:mod:`repro.telemetry.metrics`); :mod:`repro.telemetry.spans` folds
   record streams into lifecycle spans exported as Chrome trace-event
   JSON; :mod:`repro.telemetry.slo` evaluates percentile SLOs with
-  multi-window burn-rate alerts; and
-  ``python -m repro.telemetry.bench_check BENCH_sim.json`` is the
-  perf-regression sentinel over the committed bench trajectory.
+  multi-window burn-rate alerts.
+* **Layer names** (:mod:`repro.telemetry.scopes`): the ``jax.named_scope``
+  names the engines open around each layer and the kernels' names, so a
+  device profile attributes its ops to the layers; they change only the
+  compiled program's metadata.
 """
 
 from repro.telemetry.config import (

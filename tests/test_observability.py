@@ -13,13 +13,10 @@ The load-bearing guarantees, in test form:
   same admitted/completed flow, faulted or not, and conserves mass.
 * Span export emits valid Chrome trace-event JSON for a faulted serve
   run with the recovery visible.
-* ``bench_check`` passes on the repo's committed trajectory and fails on
-  a synthetically injected regression.
 """
 
 import dataclasses
 import json
-import pathlib
 
 import jax
 import jax.numpy as jnp
@@ -62,7 +59,6 @@ from repro.telemetry import (
     weighted_percentile,
     write_jsonl,
 )
-from repro.telemetry import bench_check
 from repro.telemetry.slo import bad_fraction, burn_events, evaluate_slo
 from repro.telemetry.spans import (
     controller_spans,
@@ -72,7 +68,6 @@ from repro.telemetry.spans import (
 from repro.traces.bandwidth import bandwidth_draw
 from repro.traces.faults import scheduled_failure_trace
 
-REPO = pathlib.Path(__file__).resolve().parent.parent
 HSPEC = HistogramSpec(lo=0.5, hi=64.0, n_buckets=20)
 
 
@@ -424,43 +419,6 @@ def test_fleet_records_round_trip_with_hist_and_slo(faulted_serve, tmp_path):
     assert "death edge" in text
     hist = next(r for r in records if r["type"] == "hist")
     assert hist["name"] == "sojourn" and len(hist["percentiles"]) == 2
-
-
-# ---------------------------------------------------------------------------
-# The perf-regression sentinel
-# ---------------------------------------------------------------------------
-
-def test_bench_check_series_logic():
-    stable = [100.0, 102.0, 98.0, 101.0]
-    assert bench_check.check_series(stable + [103.0])["status"] == "ok"
-    r = bench_check.check_series(stable + [400.0])
-    assert r["status"] == "regression" and r["z"] > 3.0
-    # Below the relative gate: a 3-sigma wobble on a flat series is noise.
-    tiny = bench_check.check_series(stable + [104.0], min_rel=0.25)
-    assert tiny["status"] == "ok"
-    assert bench_check.check_series([1.0, 2.0])["status"] == "skipped"
-
-
-def test_bench_check_passes_on_committed_trajectory():
-    assert bench_check.main([str(REPO / "BENCH_sim.json"), "--quiet"]) == 0
-
-
-def test_bench_check_fails_on_injected_regression(tmp_path):
-    src = json.loads((REPO / "BENCH_sim.json").read_text())
-    series = bench_check.load_series(REPO / "BENCH_sim.json")
-    label, name = next(
-        (k for k, v in series.items() if len(v) >= 4 and np.median(v) > 0)
-    )
-    spike = float(np.median(series[(label, name)]) * 10.0)
-    src.append({"label": label,
-                "benches": [{"name": name, "us_per_call": spike}]})
-    bad = tmp_path / "BENCH_sim.json"
-    bad.write_text(json.dumps(src))
-    assert bench_check.main([str(bad), "--quiet"]) == 1
-    # The untouched copy of the same file still passes.
-    good = tmp_path / "BENCH_ok.json"
-    good.write_text(json.dumps(src[:-1]))
-    assert bench_check.main([str(good), "--quiet"]) == 0
 
 
 # ---------------------------------------------------------------------------

@@ -126,3 +126,25 @@ def test_ssd_scan_compiles_for_v5e(one_chip, no_cache):
         s((b, s_len, n)),
     )
     assert "tpu_custom_call" in text
+
+
+@pytest.mark.parametrize("kernel", ["gmsa_score", "ssd_scan"])
+def test_each_kernel_carries_its_name(one_chip, no_cache, kernel):
+    """A device profile finds each kernel by the ``name=`` of its
+    ``pallas_call``: the custom call and its ``op_name`` carry it."""
+    s = lambda shape: jax.ShapeDtypeStruct(shape, jnp.float32,
+                                           sharding=one_chip)
+    if kernel == "gmsa_score":
+        text = _compiled_text(
+            lambda *a: gmsa_score(*a, interpret=False),
+            s((1, 4)), s((1, 4)), s((1,)), s((1,)), s((1, 4, 4)), s((4,)),
+        )
+    else:
+        text = _compiled_text(
+            lambda *a: ssd_scan(*a, chunk=128, interpret=False),
+            s((1, 128, 2, 64)), s((1, 128, 2)), s((2,)), s((1, 128, 128)),
+            s((1, 128, 128)),
+        )
+    calls = [line for line in text.splitlines() if "tpu_custom_call" in line]
+    assert calls and all(f"%{kernel}." in line and f'/{kernel}/pallas_call"' in line
+                         for line in calls)
