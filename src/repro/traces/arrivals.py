@@ -99,11 +99,43 @@ def serve_rate_tables(
 # Fast exact Poisson via inverse-CDF tables (EXPERIMENTS.md §Perf v4).
 #
 # jax.random.poisson's transformed-rejection sampler dominated the Monte-
-# Carlo engine's wall time (~97%). The rates here are STATIC per
+# Carlo engine's wall time on XLA:CPU (~97%). The rates here are STATIC per
 # configuration, so inverse-CDF sampling from a precomputed table is exact
 # (the distribution is already truncated at A_max by the model) and turns
-# 1.4M rejection loops into one vectorized searchsorted.
+# 1.4M rejection loops into one vectorized table lookup (_inverse_cdf).
 # ---------------------------------------------------------------------------
+
+def _count_below(tables: Array, u: Array) -> Array:
+    """(B, T) int32 count of each table's entries strictly below each draw.
+
+    For non-decreasing, finite, non-negative tables and ``u`` in [0, 1)
+    this is bitwise ``searchsorted(side="left")``. The (B, M+1, T) compare
+    is never materialised: XLA fuses it into the reduce over the table
+    axis. No gather, no loop.
+    """
+    return jnp.sum(tables[:, :, None] < u[:, None, :], axis=1, dtype=jnp.int32)
+
+
+def _binary_search(tables: Array, u: Array) -> Array:
+    """(B, T) int32 ``searchsorted(side="left")`` of each row's draws."""
+    return jax.vmap(lambda c, uu: jnp.searchsorted(c, uu, side="left"))(tables, u)
+
+
+def _inverse_cdf(tables: Array, u: Array) -> Array:
+    """(B, T) int32 inverse-CDF lookup of draws u (B, T) in tables (B, M+1).
+
+    The lowering follows the platform compiled for (EXPERIMENTS.md §Perf
+    v5, v14). On XLA:CPU the binary search (ceil(log2(M+2)) steps, 8 at 129
+    columns) beats the compare over all M+1 entries 8-10x. On the TPU each
+    search step is a data-dependent gather, the chip's weakest op, and the
+    fused compare-and-count is ~80x faster (TPU v5e, facebook_4dc's tables
+    over 1000 runs: 1.05 ms against 87.7 ms). Both return the same counts,
+    bit for bit.
+    """
+    return jax.lax.platform_dependent(
+        tables, u, tpu=_count_below, default=_binary_search
+    )
+
 
 def poisson_table(lam, max_value: int) -> np.ndarray:
     """(..., max_value+1) float32 CDF table(s) for static rate(s) ``lam``.
@@ -127,13 +159,13 @@ def poisson_pair_from_tables(
     mu_cdf: Array,
     t_slots: int,
 ) -> tuple[Array, Array]:
-    """Draw one run's (arrivals, mu) traces in ONE batched binary search.
+    """Draw one run's (arrivals, mu) traces in ONE batched table lookup.
 
     §Perf v6: the per-run Monte-Carlo build used to run two separate
     ``searchsorted`` binary-search loops (arrivals' K tables, then mu's
-    N·K tables) — two compiled while-loops per run. The tables share one
-    truncation width, so both searches batch into a single vmapped
-    ``searchsorted`` over K + N·K rows. The uniform draws are bitwise the
+    N·K tables) — two compiled while-loops per run on XLA:CPU. The tables
+    share one truncation width, so both lookups batch into a single
+    :func:`_inverse_cdf` over K + N·K rows. The uniform draws are bitwise the
     ones :func:`poisson_from_table` would consume (same keys, same
     shapes), so the realized traces are unchanged — this is purely a
     launch-count optimization.
@@ -152,7 +184,7 @@ def poisson_pair_from_tables(
     if arr_cdf.shape[-1] != m1:
         # Different truncation widths (e.g. fleet_256's a_max != mu_max):
         # pad the narrower CDF with trailing 1.0s — a monotone CDF padded
-        # at 1.0 returns identical searchsorted results for u in [0, 1).
+        # at 1.0 returns identical inverse-CDF counts for u in [0, 1).
         m1 = max(arr_cdf.shape[-1], m1)
         arr_cdf = jnp.pad(
             arr_cdf, ((0, 0), (0, m1 - arr_cdf.shape[-1])),
@@ -170,16 +202,14 @@ def poisson_pair_from_tables(
     u = jnp.concatenate(
         [u_arr.reshape(t_slots, -1).T, u_mu.reshape(t_slots, -1).T], axis=0
     )                                                              # (K+NK, T)
-    out = jax.vmap(lambda c, uu: jnp.searchsorted(c, uu, side="left"))(
-        tables, u
-    )
+    out = _inverse_cdf(tables, u)
     arrivals = out[:k_types].T.astype(jnp.float32)                 # (T, K)
     mu = out[k_types:].T.reshape(t_slots, n, k2).astype(jnp.float32)
     return arrivals, mu
 
 
 def poisson_from_table(key: Array, cdf: Array, shape: tuple) -> Array:
-    """Exact truncated-Poisson draws via inverse CDF (binary search).
+    """Exact truncated-Poisson draws via inverse CDF (:func:`_inverse_cdf`).
 
     Args:
         key: PRNG key.
@@ -187,24 +217,18 @@ def poisson_from_table(key: Array, cdf: Array, shape: tuple) -> Array:
             dims (e.g. cdf (N, K, M+1) with shape (T, N, K)).
         shape: output shape (leading axis = time/slot axis).
     Returns: float32 counts in [0, M].
-
-    §Perf v5: ``searchsorted`` (7 binary-search steps) instead of a full
-    (M+1)-wide compare+sum — the compare materialized a (T, N, K, M+1) bool
-    tensor that dominated Monte-Carlo wall time.
     """
     u = jax.random.uniform(key, shape)
     batch_dims = cdf.shape[:-1]
     m1 = cdf.shape[-1]
-    if batch_dims == ():
-        return jnp.searchsorted(cdf, u, side="left").astype(jnp.float32)
-    # Flatten table batch; move the time axis last so each table binary-
-    # searches its own draw vector.
+    # Flatten the table batch (a single table is a batch of one); move the
+    # time axis last so each table looks up its own draw vector.
     t_axes = len(shape) - len(batch_dims)
     cdf_flat = cdf.reshape(-1, m1)                              # (B, M+1)
     u_moved = jnp.moveaxis(
         u.reshape(shape[:t_axes] + (-1,)), -1, 0
     ).reshape(-1, *shape[:t_axes])                              # (B, T...)
-    out = jax.vmap(lambda c, uu: jnp.searchsorted(c, uu, side="left"))(
+    out = _inverse_cdf(
         cdf_flat, u_moved.reshape(cdf_flat.shape[0], -1)
     )                                                           # (B, prod(T))
     out = out.reshape((-1,) + shape[:t_axes])                   # (B, T...)

@@ -6,13 +6,16 @@ lower each kernel at the widths the main path runs it at — ``fleet_256``
 (K=8, N=256) for ``gmsa_score``, plain and vmapped over Monte-Carlo runs as
 ``simulate_many`` calls it, and the mamba2-2.7b layer geometry for
 ``ssd_scan`` — and check that the compiled program holds the kernel. One
-more test bounds the TPU compile time of the faulted placed engine.
+more test bounds the TPU compile time of the faulted placed engine, and one
+pins the trace draws' lowering: a fused count on the TPU, the binary search
+on the CPU.
 
 The topology is described inside a fixture, never at import: only one
 process may load the TPU library, and every test worker imports this file.
 """
 
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -114,6 +117,31 @@ def test_placed_engine_compiles_for_v5e_in_seconds(one_chip, no_cache):
     t0 = time.perf_counter()
     lowered.compile()
     assert time.perf_counter() - t0 < 60.0
+
+
+def test_trace_draws_compile_to_one_count_for_v5e(one_chip, no_cache):
+    """``facebook_4dc``'s per-run draws over 1000 runs, as ``simulate_many``
+    builds them: on the TPU one compare-and-count with no loop, no gather
+    and no materialised (runs, K+N·K, M+1, T) compare; on the CPU the
+    binary search's loop."""
+    from repro.configs.facebook_4dc import PaperSimConfig, make_sim_builder
+
+    cfg = PaperSimConfig()
+    _, build = make_sim_builder(cfg)
+    draws = lambda keys: tuple(jax.vmap(build)(keys)[:2])
+    key_dtype = jax.random.key(0).dtype
+    runs = 1000
+    compiled = jax.jit(draws).lower(
+        jax.ShapeDtypeStruct((runs,), key_dtype, sharding=one_chip)).compile()
+    loops = re.compile(r"\b(while|gather)\(")
+    assert not loops.search(compiled.as_text())
+    tables = cfg.k_types + cfg.n_sites * cfg.k_types
+    compare_bytes = runs * tables * (int(cfg.a_max) + 1) * cfg.t_slots
+    assert compiled.memory_analysis().temp_size_in_bytes < compare_bytes / 10
+
+    cpu_text = jax.jit(draws).lower(
+        jax.ShapeDtypeStruct((runs,), key_dtype)).compile().as_text()
+    assert re.search(r"\bwhile\(", cpu_text)
 
 
 def test_ssd_scan_compiles_for_v5e(one_chip, no_cache):
