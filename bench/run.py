@@ -23,11 +23,17 @@ the persistent cache) is ``setup_s``. Then the window runs calls for
   two calls) under the profiler and reports the per-layer metrics,
   ``busy_s``/``window_s`` and the ``breakdown``.
 
-After the window ``CHECK_CALLS`` of its calls, drawn from the seed, are compared
-with the plain reference (``bench/reference.py``); the numbers compared and
-their limits end standard error and the result line, which is the last line
-of standard output. Without an accelerator, or with fewer chips than the
-cell asks for, the run exits non-zero and prints no result.
+The window holds the answer and digest of ``CHECK_CALLS`` of its calls,
+picked while it runs by reservoir sampling from the seed, and drops every
+other call's once its latency is recorded. The set-up heap is frozen out of
+the collector's reach before the window opens. Standard error names the
+collector's runs in the window and every call over ten medians, with the
+process's CPU time and context switches across it (``host_watch.py``).
+After the window the held calls are compared with the plain reference
+(``bench/reference.py``); the numbers compared and their limits end standard
+error and the result line, which is the last line of standard output.
+Without an accelerator, or with fewer chips than the cell asks for, the run
+exits non-zero and prints no result.
 """
 
 from __future__ import annotations
@@ -38,6 +44,7 @@ T_START = time.perf_counter()
 
 import argparse  # noqa: E402
 import contextlib  # noqa: E402
+import gc  # noqa: E402
 import json  # noqa: E402
 import math  # noqa: E402
 import os  # noqa: E402
@@ -135,6 +142,25 @@ class CompileCounter:
             self.compiles += 1
 
 
+class Reservoir:
+    """Holds ``k`` of a stream of unknown length (Algorithm R): once ``n``
+    items have been offered, each is in ``held`` with probability ``k / n``.
+    The draws come from ``rng`` alone, so the pick is fixed by the seed, and
+    the program cannot know which call it is."""
+
+    def __init__(self, k: int, rng):
+        self.k, self.rng, self.seen, self.held = k, rng, 0, []
+
+    def offer(self, item) -> None:
+        self.seen += 1
+        if len(self.held) < self.k:
+            self.held.append(item)
+        else:
+            j = int(self.rng.random() * self.seen)
+            if j < self.k:
+                self.held[j] = item
+
+
 def _quantile(values: list, q: float) -> float:
     import numpy as np
 
@@ -149,6 +175,7 @@ def run_cell(spec: dict, seed: int, seconds: float, trace: bool,
 
     import cell as cell_mod
     import compare
+    import host_watch
     import plugins
     import reference
     from trace_reduce import layer_of, reduce_trace
@@ -167,50 +194,62 @@ def run_cell(spec: dict, seed: int, seconds: float, trace: bool,
     rng = np.random.default_rng(seed)
     rows_np = np.sort(rng.choice(c.n_runs, size=min(SLOT_ROWS, c.n_runs), replace=False))
     rows = jax.device_put(rows_np.astype(np.int32), c.home)
+    pick = Reservoir(CHECK_CALLS, rng)          # drawn after rows_np: the rows stay
     span = jax.profiler.TraceAnnotation if trace else (lambda _n: contextlib.nullcontext())
+    clock = host_watch.CallClocks()
 
     def one(i: int):
         with span("bench_key"):
             idx = np.int32(i)
+        before = host_watch.clocks()
         t0 = time.perf_counter()
         with span("bench_submit"):
             ans, dig = c.call(base, idx, rows)
         with span("bench_fetch"):
             ans = np.asarray(ans)
-        return ans, dig, time.perf_counter() - t0
+        lat = time.perf_counter() - t0
+        clock.record(lat, before)
+        return ans, dig
 
-    ans, dig, _ = one(0)
-    jax.block_until_ready(dig)
+    jax.block_until_ready(one(0))
     setup_s = time.perf_counter() - T_START
     log(f"set-up {setup_s:.6f} s (process start to the end of the warm-up call)")
+    clock = host_watch.CallClocks()             # the window's calls alone
 
     tdir = CACHE / "trace" / w["name"]
-    if trace:
-        shutil.rmtree(tdir, ignore_errors=True)
-        jax.profiler.start_trace(str(tdir))
-        limit = min(TRACE_SECONDS, seconds)
-    else:
-        limit = seconds
-    calls = []
-    counter.active = True
-    w0 = time.perf_counter()
-    with span("bench_window"):
-        i = 1
-        while True:
-            ans, dig, lat = one(i)
-            calls.append((i, ans, dig, lat))
-            i += 1
-            if time.perf_counter() - w0 >= limit and (not trace or len(calls) >= 2):
-                break
-    window = time.perf_counter() - w0
+    limit = min(TRACE_SECONDS, seconds) if trace else seconds
+    # What set-up made (modules, traced and compiled programs) lives to the
+    # end: the collector need not rescan it in the window.
+    gc.collect()
+    gc.freeze()
+    try:
+        if trace:
+            shutil.rmtree(tdir, ignore_errors=True)
+            jax.profiler.start_trace(str(tdir))
+        counter.active = True
+        with host_watch.Collector() as collector:
+            w0 = time.perf_counter()
+            with span("bench_window"):
+                i = 1
+                while True:
+                    pick.offer((i, *one(i)))    # (index, answer, digest)
+                    i += 1
+                    if time.perf_counter() - w0 >= limit and (not trace or i > 2):
+                        break
+            window = time.perf_counter() - w0
+    finally:
+        gc.unfreeze()
     counter.active = False
     if trace:
         jax.profiler.stop_trace()
-    lats = [x[3] for x in calls]
-    log(f"window {window:.6f} s: {len(calls)} calls of {c.n_runs} runs, call median "
+    lats = clock.lat
+    log(f"window {window:.6f} s: {len(lats)} calls of {c.n_runs} runs, call median "
         f"{_quantile(lats, 50) * 1e3:.6f} ms, p95 {_quantile(lats, 95) * 1e3:.6f} ms, "
         f"longest {max(lats) * 1e3:.6f} ms, all calls {sum(lats):.6f} s; "
         f"inside the window {counter.compiles} compiles, {counter.traces} traces")
+    log(collector.report())
+    for line in clock.slow():
+        log(line)
 
     def peak_bytes() -> int | None:
         peaks_b = []
@@ -232,7 +271,9 @@ def run_cell(spec: dict, seed: int, seconds: float, trace: bool,
         shutil.rmtree(tdir, ignore_errors=True)
         device["busy_s"] = sum(tr.busy_s) / len(tr.busy_s)
         device["window_s"] = tr.window_s
-        ctx = {"calls": len(calls), "n_runs": c.n_runs, "chips": len(devs),
+        log(f"the trace covers {tr.calls} of the window's {len(lats)} calls, "
+            f"{tr.window_s:.6f} s")
+        ctx = {"calls": tr.calls, "n_runs": c.n_runs, "chips": len(devs),
                "peaks": peaks, **c.shapes}
         for m in spec["per_layer"]:
             v = plugins.load("metrics", m["name"]).read(tr, ctx)
@@ -241,7 +282,7 @@ def run_cell(spec: dict, seed: int, seconds: float, trace: bool,
         result["breakdown"] = tr.breakdown(layer_of)
     else:
         e2e = {
-            "runs_per_s": len(calls) * c.n_runs / window,
+            "runs_per_s": len(lats) * c.n_runs / window,
             "call_p95_ms": _quantile(lats, 95) * 1e3,
             "setup_s": setup_s,
         }
@@ -249,10 +290,8 @@ def run_cell(spec: dict, seed: int, seconds: float, trace: bool,
             metrics[m["name"]] = {"value": e2e[m["name"]], "unit": m["unit"]}
 
     # The comparison, once the window has closed and the peak is read.
-    k = min(CHECK_CALLS, len(calls))
-    picked = sorted(rng.choice(len(calls), size=k, replace=False).tolist())
-    sample = [(calls[j][0], calls[j][1], jax.device_get(calls[j][2])) for j in picked]
-    del calls, dig
+    sample = [(i, a, jax.device_get(d)) for i, a, d in sorted(pick.held, key=lambda h: h[0])]
+    del pick
     cfg = spec["config"]
     f = cfg["fields"]
     want = c.entry.shapes(c.n_runs, len(rows_np), f["t_slots"], f["k_types"])

@@ -1,7 +1,9 @@
 """The reduction from a device trace to per-layer metrics, on small traces
 recorded on a TPU v5e by the harness (``bench/tests/data``)."""
 
+import gzip
 import importlib.util
+import json
 from pathlib import Path
 
 import pytest
@@ -55,6 +57,42 @@ def test_every_op_lands_in_a_known_layer_and_trace_generation_leads(path):
     assert layers <= LAYERS
     gen = tr.self_seconds(lambda s: layer_of(s) == "trace generation")
     assert gen > 0.5 * tr.busy_s[0]
+
+
+@pytest.mark.parametrize("path", TRACES, ids=lambda p: p.name)
+def test_the_trace_counts_the_calls_it_covers(path):
+    assert reduce_trace(path).calls == 2          # each trace holds two calls
+
+
+def _cut(path, out):
+    """A copy of ``path`` whose device events stop halfway through the
+    second call, as the trace's cap of events cuts a window of many calls."""
+    doc = json.load(gzip.open(path, "rt"))
+    threads = {(e["pid"], e["tid"]): e["args"]["name"] for e in doc["traceEvents"]
+               if e.get("ph") == "M" and e["name"] == "thread_name"}
+    device = [e for e in doc["traceEvents"] if e.get("ph") == "X" and threads.get(
+        (e["pid"], e["tid"])) in ("XLA Ops", "XLA Modules")]
+    cut = sorted(e["ts"] + 0.5 * e["dur"] for e in device
+                 if threads[(e["pid"], e["tid"])] == "XLA Modules")[1]
+    dropped = {id(e) for e in device if e["ts"] > cut}
+    doc["traceEvents"] = [e for e in doc["traceEvents"] if id(e) not in dropped]
+    with gzip.open(out, "wt") as fh:
+        json.dump(doc, fh)
+    return out
+
+
+def test_a_cut_trace_reads_per_call_over_the_calls_it_covers(tmp_path):
+    path = DATA / "paper_mc.trace.json.gz"
+    whole = reduce_trace(path)
+    cut = reduce_trace(_cut(path, tmp_path / "cut.trace.json.gz"))
+    assert cut.calls == 1
+    assert cut.window_s < 0.6 * whole.window_s    # ends with the first call
+    assert cut.busy_s[0] < 0.6 * whole.busy_s[0]
+    assert 0 < reader("device_idle_pct").read(cut, cell_ctx(path, calls=1)) < 100
+    for name in ("tracegen_ms", "scan_body_ms", "dispatch_ms"):
+        per_call = reader(name).read(cut, cell_ctx(path, calls=cut.calls))
+        assert per_call == pytest.approx(
+            reader(name).read(whole, cell_ctx(path, calls=whole.calls)), rel=0.05)
 
 
 def test_the_kernel_is_counted_in_the_dispatch_layer():

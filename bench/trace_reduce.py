@@ -17,8 +17,14 @@ What is read:
   nested in them, so a loop's own overhead lands in the layer of its body;
 * a fusion carries the name stack of the op XLA kept as its root, so a
   fusion that spans two scopes is counted, whole, in the root's scope;
-* the window is the host span ``bench_window``; the ops counted are those
-  that start inside it, and a device's busy time is the union of their
+* the window is the host span ``bench_window``, ended at the close of the
+  last call it covers: the exported trace stops at a cap of events
+  (1,000,000), which in a window of many short calls cuts off the later
+  ones. A call is covered where its execution of the call program (the
+  ``jit_call`` module of ``bench/cell.py`` on the device's ``XLA Modules``
+  line) holds as many ops as the fullest execution does; ``calls`` counts
+  them, and per-call readings divide by it. The ops counted are those that
+  start inside the window, and a device's busy time is the union of their
   intervals;
 * idle gaps are the holes in that union, named by the host span (``bench_submit``,
   ``bench_fetch``, ``bench_key``) that covers the gap's middle once the
@@ -27,6 +33,7 @@ What is read:
 
 from __future__ import annotations
 
+import bisect
 import collections
 import gzip
 import json
@@ -35,6 +42,8 @@ from dataclasses import dataclass, field
 from pathlib import Path
 
 HOST_SPANS = ("bench_submit", "bench_fetch", "bench_key")
+#: The module name of the cell's call (``jax.jit`` of ``call`` in ``bench/cell.py``).
+CALL_PROGRAM = "jit_call("
 
 #: Layers by the scope an op runs in, first match wins.
 LAYERS = (
@@ -71,6 +80,7 @@ class Trace:
     busy_s: list                       # per device
     ops: list                          # [(device, Op)]
     gaps: list                         # [(host span name, seconds)]
+    calls: int = 0                     # calls the trace covers on every device
 
     def self_seconds(self, pred) -> float:
         """Self time of the ops whose scope satisfies ``pred``, averaged
@@ -160,7 +170,8 @@ def reduce_trace(path: Path) -> Trace:
                 dev_ops[pid].append(Op(float(e["ts"]), float(e["dur"]), e["name"],
                                        a.get("tf_op", ""), a.get("hlo_category", "")))
             elif tname == "XLA Modules":
-                modules[pid].append((float(e["ts"]), float(e["ts"]) + float(e["dur"])))
+                modules[pid].append((float(e["ts"]), float(e["ts"]) + float(e["dur"]),
+                                     e["name"]))
         elif procs.get(pid, "").startswith("/host"):
             if e["name"] == "bench_window":
                 window = (float(e["ts"]), float(e["ts"]) + float(e["dur"]))
@@ -175,12 +186,20 @@ def reduce_trace(path: Path) -> Trace:
     # Align the device clock to the host's on the module launches.
     offsets = []
     for pid in dev_pids:
-        for (s, _), h in zip(sorted(modules[pid]), sorted(launches)):
+        for (s, _, _), h in zip(sorted(modules[pid]), sorted(launches)):
             offsets.append(h - s)
     offset = sorted(offsets)[len(offsets) // 2] if offsets else 0.0
     if window is None:                 # a trace taken without the harness
         spans = [m for pid in dev_pids for m in modules[pid]]
-        window = (min(s for s, _ in spans) + offset, max(e for _, e in spans) + offset)
+        window = (min(s for s, _, _ in spans) + offset,
+                  max(e for _, e, _ in spans) + offset)
+
+    covered = {pid: _covered_calls(modules[pid], dev_ops[pid], window[0] - offset,
+                                   window[1] - offset) for pid in dev_pids}
+    calls = 0
+    if all(covered.values()):
+        window = (window[0], min(window[1], min(c[-1][1] for c in covered.values()) + offset))
+        calls = min(sum(e + offset <= window[1] for _, e in c) for c in covered.values())
 
     ops_all, busy, gaps = [], [], []
     for pid in sorted(dev_pids, key=lambda p: dev_pids[p][1]):
@@ -200,7 +219,20 @@ def reduce_trace(path: Path) -> Trace:
     platform = next(iter(dev_pids.values()))[0]
     return Trace(platform=platform, n_devices=len(dev_pids),
                  window_s=(window[1] - window[0]) * 1e-6, busy_s=busy,
-                 ops=ops_all, gaps=gaps)
+                 ops=ops_all, gaps=gaps, calls=calls)
+
+
+def _covered_calls(modules: list, ops: list, lo: float, hi: float) -> list:
+    """(start, end), in order, of the call program's executions that start in
+    ``[lo, hi]`` (device clock) and hold as many ops as the fullest of them:
+    every execution of one program holds the same ops, and one that the
+    trace's cap cut holds fewer."""
+    starts = sorted(o.start for o in ops)
+    runs = [(s, e, bisect.bisect_right(starts, e) - bisect.bisect_left(starts, s))
+            for s, e, name in sorted(modules)
+            if name.startswith(CALL_PROGRAM) and lo <= s <= hi]
+    full = max((n for _, _, n in runs), default=0)
+    return [(s, e) for s, e, n in runs if n == full > 0]
 
 
 def _set_nesting(ops: list) -> None:
