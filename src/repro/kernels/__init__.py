@@ -5,7 +5,9 @@ interpret mode against the pure-jnp oracle (tests/test_kernels.py):
 
 * ``gmsa_score`` — the paper's per-slot dispatch inner loop at fleet scale:
   fused cost matvec (MXU) + drift add (VPU) + running argmin reduction, one
-  VMEM pass over the (K, N, N) task-allocation tensor.
+  VMEM pass over the (K, N, N) task-allocation tensor. Vmapped over
+  Monte-Carlo runs at N < 128, one launch decides every run, the runs on
+  the lane axis.
 * ``ssd_scan``   — Mamba-2 chunked SSD forward (the long_500k hot spot):
   intra-chunk attention-form + cross-chunk recurrence carried in VMEM
   scratch across the sequential chunk grid.

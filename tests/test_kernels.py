@@ -155,6 +155,162 @@ def test_fleet256_end_to_end_kernel_vs_ref():
 
 
 # ---------------------------------------------------------------------------
+# gmsa_score vmapped over Monte-Carlo runs — the runs-on-lanes kernel below
+# N = 128, the per-run kernel from there up
+# ---------------------------------------------------------------------------
+
+def _runs_inputs(key, runs, k, n, r_runs, w_runs):
+    """R runs of ``_gmsa_inputs``; r and wpue shared unless batched."""
+    keep = (True,) * 4 + (r_runs, w_runs)
+    draw = lambda kk: _gmsa_inputs(kk, k, n, jnp.float32)
+    per_run = jax.jit(jax.vmap(lambda kk: [x for x, b in zip(draw(kk), keep) if b]))(
+        jax.random.split(key, runs))
+    shared = [x for x, b in zip(draw(key), keep) if not b]
+    return tuple(per_run.pop(0) if b else shared.pop(0) for b in keep)
+
+
+def _runs_axes(r_runs, w_runs):
+    return (0, 0, 0, 0, 0 if r_runs else None, 0 if w_runs else None)
+
+
+def _score_scale(q, mu, a, vp, r, wpue):
+    """Each score's size before cancellation: a * (|q| + |mu| + vp * cost)."""
+    cost = jnp.einsum("kij,j->ki", r, wpue, precision=jax.lax.Precision.HIGHEST)
+    return jnp.abs(a)[:, None] * (jnp.abs(q) + jnp.abs(mu)
+                                  + jnp.abs(vp)[:, None] * cost)
+
+
+def _pallas_calls(jaxpr):
+    """Every ``pallas_call`` equation in ``jaxpr`` and the jaxprs it nests."""
+    found = []
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            found.append(eqn)
+        for value in eqn.params.values():
+            for sub in value if isinstance(value, (tuple, list)) else [value]:
+                inner = getattr(sub, "jaxpr", sub)
+                if hasattr(inner, "eqns"):
+                    found += _pallas_calls(inner)
+    return found
+
+
+_RUN_CASES = [
+    pytest.param(k, n, runs, r_runs, w_runs,
+                 id=f"K{k}-N{n}-R{runs}-" + {(False, False): "shared",
+                                             (True, False): "r_runs",
+                                             (True, True): "r_wpue_runs"}[r_runs, w_runs])
+    for k, n in [(1, 4), (3, 5), (8, 127)]
+    for r_runs, w_runs, run_counts in [(False, False, (1, 37, 1000)),
+                                       (True, False, (1, 37)),
+                                       (True, True, (1, 37))]
+    for runs in run_counts
+]
+
+
+@pytest.mark.parametrize("k,n,runs,r_runs,w_runs", _RUN_CASES)
+def test_vmapped_gmsa_score_matches_ref_per_run(k, n, runs, r_runs, w_runs):
+    args = _runs_inputs(jax.random.key(runs * 1000 + n), runs, k, n, r_runs, w_runs)
+    axes = _runs_axes(r_runs, w_runs)
+    s, b = jax.vmap(lambda *x: gmsa_score(*x, interpret=True), in_axes=axes)(*args)
+    s_ref, b_ref = jax.vmap(gmsa_score_ref, in_axes=axes)(*args)
+    scale = np.asarray(jax.vmap(_score_scale, in_axes=axes)(*args))
+    assert s.shape == (runs, k, n) and b.shape == (runs, k)
+    s, s_ref = np.asarray(s), np.asarray(s_ref)
+    assert np.all(np.abs(s - s_ref) <= 1e-5 * scale)
+    low2 = np.partition(s_ref.astype(np.float64), 1, axis=-1)
+    near_tie = (low2[..., 1] - low2[..., 0]) <= 1e-5 * scale.max(axis=-1)
+    assert np.all((np.asarray(b) == np.asarray(b_ref)) | near_tie)
+
+
+@pytest.mark.parametrize("r_runs", [False, True], ids=["shared", "r_runs"])
+def test_vmapped_gmsa_score_ties_go_to_the_lowest_site(r_runs):
+    """Bitwise-equal scores: every site equal, or two sites equal at the
+    minimum; the lowest index wins, as the per-run kernel's argmin."""
+    runs, k, n = 37, 2, 5
+    row = jnp.array([0.25, 0.5, 0.125, 0.125])
+    r = jnp.broadcast_to(jnp.concatenate([row, jnp.zeros(1)]), (k, n, n))
+    wpue = jnp.array([4.0, 8.0, 2.0, 16.0, 1.0])        # same cost at every site
+    tied = np.zeros((runs, k, n), np.float32)           # run 0, type 0: all tie
+    tied[1:, 0] = 7.0
+    lo, hi = np.arange(1, runs) % (n - 1), np.arange(1, runs) % (n - 1) + 1
+    tied[np.arange(1, runs), 0, lo] = tied[np.arange(1, runs), 0, hi] = 3.0
+    tied[:, 1] = [9.0, 2.0, 2.0, 2.0, 5.0]              # type 1: sites 1-3 tie
+    q, mu = jnp.asarray(tied), jnp.zeros((runs, k, n))
+    a, vp = jnp.full((runs, k), 3.0), jnp.full((runs, k), 0.5)
+    if r_runs:
+        r = jnp.broadcast_to(r, (runs,) + r.shape)
+    s, b = jax.vmap(lambda *x: gmsa_score(*x, interpret=True),
+                    in_axes=_runs_axes(r_runs, False))(q, mu, a, vp, r, wpue)
+    want = np.stack([np.concatenate([[0], lo]), np.full(runs, 1)], axis=1)
+    np.testing.assert_array_equal(np.asarray(b), want)
+    # The ties are exact: the tied sites' scores are bitwise equal.
+    s = np.asarray(s)
+    assert np.all(s[1:, 0][np.arange(runs - 1), lo] == s[1:, 0][np.arange(runs - 1), hi])
+
+
+@pytest.mark.parametrize("r_runs", [False, True], ids=["shared", "r_runs"])
+def test_nested_vmap_over_v_and_runs_is_one_launch(r_runs):
+    """A V sweep around the runs vmap: one launch for every (V, run) pair,
+    the same answers as the per-run oracle."""
+    runs, k, n = 37, 3, 5
+    q, mu, a, _, r, wpue = _runs_inputs(jax.random.key(5), runs, k, n, r_runs, False)
+    vs = jnp.array([0.5, 2.0, 8.0])
+
+    def sweep(score):
+        def at(v):
+            per_run = lambda q_, mu_, a_, r_: score(q_, mu_, a_, v * jnp.ones(k), r_, wpue)
+            return jax.vmap(per_run, in_axes=(0, 0, 0, 0 if r_runs else None))(q, mu, a, r)
+        return jax.vmap(at)
+
+    kernel = sweep(lambda *x: gmsa_score(*x, interpret=True))
+    (launch,) = _pallas_calls(jax.make_jaxpr(kernel)(vs).jaxpr)
+    assert launch.params["name"] == "gmsa_score"
+    assert launch.params["grid_mapping"].grid[0] == 1     # 111 runs, one tile
+    s, b = kernel(vs)
+    s_ref, b_ref = sweep(gmsa_score_ref)(vs)
+    assert s.shape == (3, runs, k, n) and b.shape == (3, runs, k)
+    np.testing.assert_allclose(np.asarray(s), np.asarray(s_ref), rtol=1e-5, atol=1e-3)
+    np.testing.assert_array_equal(np.asarray(b), np.asarray(b_ref))
+
+
+def test_runs_vmap_engages_one_lanes_launch_below_n_t_and_per_run_grid_above():
+    """The path each shape takes, read from the jaxpr: at the Facebook-4DC
+    widths (K=1, N=4, R=1000, shared r) one ``gmsa_score`` launch whose grid
+    walks run tiles; at the ``fleet_256`` widths (K=8, N=256), and where no
+    run tile fits, the per-run kernel, its grid the unbatched call's with
+    the runs in front."""
+    from repro.kernels.gmsa_score.kernel import lanes_tiles
+
+    sd = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.float32)
+    score = lambda *x: gmsa_score(*x, interpret=False)
+    runs_of = lambda k, n, runs: jax.make_jaxpr(
+        jax.vmap(score, in_axes=(0, 0, 0, None, None, 0))
+    )(sd(runs, k, n), sd(runs, k, n), sd(runs, k), sd(k), sd(k, n, n), sd(runs, n)).jaxpr
+    plain_of = lambda k, n: jax.make_jaxpr(score)(
+        sd(k, n), sd(k, n), sd(k), sd(k), sd(k, n, n), sd(n)).jaxpr
+
+    (lanes,) = _pallas_calls(runs_of(1, 4, 1000))
+    r_t, _ = lanes_tiles(1, 4, 1000, False, False)
+    assert lanes.params["name"] == "gmsa_score"
+    assert lanes.params["grid_mapping"].grid == (-(-1000 // r_t), 1)
+    assert r_t <= 1024 and r_t % 128 == 0
+
+    for k, n in [(1, 4), (8, 256)]:
+        (plain,) = _pallas_calls(plain_of(k, n))
+        assert "minval_ref" in plain.params["jaxpr"].debug_info.arg_names
+    (fleet,) = _pallas_calls(runs_of(8, 256, 16))
+    assert fleet.params["name"] == "gmsa_score"
+    assert fleet.params["grid_mapping"].grid == (16,) + plain.params["grid_mapping"].grid
+    assert fleet.params["jaxpr"].debug_info.arg_names == plain.params["jaxpr"].debug_info.arg_names
+
+    # Below N_T, but no run tile fits the VMEM budget: the per-run kernel.
+    assert lanes_tiles(64, 127, 16, False, False) is None
+    (wide,) = _pallas_calls(runs_of(64, 127, 16))
+    assert wide.params["grid_mapping"].grid[0] == 16
+    assert "minval_ref" in wide.params["jaxpr"].debug_info.arg_names
+
+
+# ---------------------------------------------------------------------------
 # ssd_scan
 # ---------------------------------------------------------------------------
 

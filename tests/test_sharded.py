@@ -197,6 +197,29 @@ def test_carried_r_matches_ref_on_drifting_trace(paper_setup):
         )
 
 
+@pytest.mark.parametrize("carried", [False, True], ids=["static_r", "carried_r"])
+def test_simulate_many_kernel_matches_table_on_every_run(paper_setup, carried):
+    """Monte-Carlo runs through the kernel policy — one runs-on-lanes launch
+    per slot, r shared (static) or drifting differently in every run
+    (carried) — make every run's decisions of the table path."""
+    cfg, template, build, _, _ = paper_setup
+    key = jax.random.key(13)
+    if carried:
+        r_tv = drifting_r(template, cfg.t_slots)
+
+        def run_build(k):
+            mix = jax.random.uniform(jax.random.fold_in(k, 1))
+            return build(k)._replace(r=(1 - mix) * r_tv + mix * jnp.roll(r_tv, 2, -1))
+
+        policy = make_kernel_policy(p_it=template.p_it)
+    else:
+        run_build, policy = build, make_kernel_policy(template.r, template.p_it)
+    ref = simulate_many(run_build, gmsa_policy, key, 9)
+    out = simulate_many(run_build, policy, key, 9)
+    np.testing.assert_array_equal(np.asarray(ref.f_trace), np.asarray(out.f_trace))
+    np.testing.assert_array_equal(np.asarray(ref.cost), np.asarray(out.cost))
+
+
 def test_static_r_policy_raises_on_time_varying_trace(paper_setup):
     cfg, template, _, _, _ = paper_setup
     inp_tv = template._replace(r=drifting_r(template, cfg.t_slots))

@@ -4,7 +4,8 @@ Interpret mode (tests/test_kernels.py) checks what a kernel computes; only
 the TPU compiler (Mosaic) checks that its blocks tile and fit. These tests
 lower each kernel at the widths the main path runs it at — ``fleet_256``
 (K=8, N=256) for ``gmsa_score``, plain and vmapped over Monte-Carlo runs as
-``simulate_many`` calls it, and the mamba2-2.7b layer geometry for
+``simulate_many`` calls it, the Facebook-4DC widths (K=1, N=4, 1000 runs)
+for its runs-on-lanes kernel, and the mamba2-2.7b layer geometry for
 ``ssd_scan`` — and check that the compiled program holds the kernel. One
 more test bounds the TPU compile time of the faulted placed engine, and one
 pins the trace draws' lowering: a fused count on the TPU, the binary search
@@ -86,6 +87,25 @@ def test_gmsa_score_vmapped_over_runs_compiles_for_v5e(one_chip, no_cache):
         s((K, N, N)), s((RUNS, N)),
     )
     assert "tpu_custom_call" in text
+
+
+@pytest.mark.parametrize("r_runs", [False, True], ids=["shared_r", "r_runs"])
+def test_gmsa_score_on_lanes_compiles_for_v5e(one_chip, no_cache, r_runs):
+    """``facebook_4dc``'s dispatch vmapped over 1000 runs, r shared (the
+    static-r policy) or per run (the placement controller's carried r):
+    one kernel launch, named ``gmsa_score``."""
+    k, n, runs = 1, 4, 1000
+    s = lambda shape: jax.ShapeDtypeStruct(shape, jnp.float32,
+                                           sharding=one_chip)
+    per_run = jax.vmap(lambda *a: gmsa_score(*a, interpret=False),
+                       in_axes=(0, 0, 0, None, 0 if r_runs else None, None))
+    text = _compiled_text(
+        per_run,
+        s((runs, k, n)), s((runs, k, n)), s((runs, k)), s((k,)),
+        s(((runs,) if r_runs else ()) + (k, n, n)), s((n,)),
+    )
+    calls = [line for line in text.splitlines() if "tpu_custom_call" in line]
+    assert len(calls) == 1 and '/gmsa_score/pallas_call"' in calls[0]
 
 
 def test_placed_engine_compiles_for_v5e_in_seconds(one_chip, no_cache):
