@@ -27,6 +27,8 @@ import jax.numpy as jnp
 from jax import Array
 from jax.nn import one_hot
 
+from repro.core.policy import declare
+
 # Static upper bound on per-slot arrivals of one job type; the exact
 # multinomial sampler draws this many candidate picks and masks the tail.
 # Configs assert A_max <= MAX_SLOT_ARRIVALS.
@@ -39,8 +41,7 @@ def data_dispatch(key, q: Array, arrivals: Array, mu: Array, e: Array, aux: Arra
     return aux.T  # (K, N) -> (N, K)
 
 
-data_dispatch.state_independent = True
-data_dispatch.consumes_key = False
+declare(data_dispatch, keyed=False, state_independent=True)
 
 
 def random_dispatch(key, q: Array, arrivals: Array, mu: Array, e: Array, aux: Array, scalar=0.0) -> Array:
@@ -75,7 +76,7 @@ def _multinomial_uniform(key, count: Array, n: int) -> Array:
     return hist[:n]
 
 
-random_dispatch.state_independent = True
+declare(random_dispatch, state_independent=True)
 
 
 def jsq_dispatch(key, q: Array, arrivals: Array, mu: Array, e: Array, aux: Array, scalar=0.0) -> Array:
@@ -85,7 +86,7 @@ def jsq_dispatch(key, q: Array, arrivals: Array, mu: Array, e: Array, aux: Array
     return one_hot(best, q.shape[0], dtype=q.dtype).T
 
 
-jsq_dispatch.consumes_key = False
+declare(jsq_dispatch, keyed=False)
 
 
 def greedy_cost_dispatch(key, q: Array, arrivals: Array, mu: Array, e: Array, aux: Array, scalar=0.0) -> Array:
@@ -95,8 +96,7 @@ def greedy_cost_dispatch(key, q: Array, arrivals: Array, mu: Array, e: Array, au
     return one_hot(best, q.shape[0], dtype=q.dtype).T
 
 
-greedy_cost_dispatch.state_independent = True
-greedy_cost_dispatch.consumes_key = False
+declare(greedy_cost_dispatch, keyed=False, state_independent=True)
 
 
 def static_placement_rule(d: Array, obs) -> Array:

@@ -50,11 +50,6 @@ def manager_energy_cost(omega: Array, pue: Array, r: Array, p_it: Array) -> Arra
     return e * p_it[:, None]
 
 
-def manager_energy(pue: Array, r: Array, p_it: Array) -> Array:
-    """Per-job *energy* (not cost): E[k, i] = P^k * sum_j PUE_j * r[k, i, j]."""
-    return jnp.einsum("kij,j->ki", r, pue, precision=HIGHEST) * p_it[:, None]
-
-
 def slot_cost(f: Array, arrivals: Array, e: Array) -> Array:
     """System energy cost of one slot, Cost(t) (scalar).
 

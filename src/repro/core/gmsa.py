@@ -29,6 +29,8 @@ import jax
 import jax.numpy as jnp
 from jax import Array
 
+from repro.core.policy import declare
+
 
 @dataclasses.dataclass(frozen=True)
 class GMSAConfig:
@@ -163,7 +165,7 @@ def gmsa_policy(key, q, arrivals, mu, e, aux, scalar):
     return gmsa_dispatch(q, arrivals, mu, e, scalar)
 
 
-gmsa_policy.consumes_key = False
+declare(gmsa_policy, keyed=False)
 
 
 def dispatch_fn(v: float):
@@ -178,8 +180,7 @@ def dispatch_fn(v: float):
         del key, aux, scalar
         return gmsa_dispatch(q, arrivals, mu, e, v)
 
-    _policy.consumes_key = False
-    return _policy
+    return declare(_policy, keyed=False)
 
 
 def make_kernel_policy(
@@ -196,43 +197,40 @@ def make_kernel_policy(
     pass (:mod:`repro.kernels.gmsa_score`). V rides in as the simulator's
     traced ``scalar``, exactly like :func:`gmsa_policy`.
 
-    Two ratio-tensor modes:
+    Two ratio-tensor modes, both declared ``reads`` of raw per-slot prices
+    (:class:`repro.core.policy.PolicySpec`):
 
-    * ``r=None`` (carried-r) — the policy declares ``wants_r = True`` and
-      reads the ratio tensor in force *this slot* from its aux,
+    * ``r=None`` (carried-r) — ``reads="wpue_r"``: the policy reads the
+      ratio tensor in force *this slot* from its aux,
       ``aux = (data_dist, omega_t * pue_t, r_t)``: the engines slice a
       time-varying ``(T, K, N, N)`` trace per slot, and the placement
       controller hands the carried ``r_c``/``r_e`` its epoch rebuilds and
       recovery re-placements actually produced. This is the only mode the
       controller accepts.
-    * explicit ``(K, N, N)`` ``r`` — statically bound, as before. The
-      policy is marked ``static_r = True`` and the engines raise loudly
-      if a time-varying ratio trace reaches it (the kernel would silently
-      dispatch on stale ratios).
+    * explicit ``(K, N, N)`` ``r`` — ``reads="wpue"``, ``binds_r=True``:
+      statically bound, and the engines raise loudly if a time-varying
+      ratio trace reaches it (the kernel would silently dispatch on stale
+      ratios).
 
-    Either way the policy declares ``wants_wpue = True``, so
-    :func:`repro.core.simulator.simulate` hands it raw per-slot prices —
-    this is what lets an N = 256 ``configs.fleet_256`` run complete
-    end-to-end through the kernel (interpret mode on CPU/CI, compiled on
-    TPU; ``impl="ref"`` selects the pure-jnp oracle instead — the
-    fallback when Pallas is unavailable).
+    Reading raw prices is what lets an N = 256 ``configs.fleet_256`` run
+    complete end-to-end through the kernel (interpret mode on CPU/CI,
+    compiled on TPU; ``impl="ref"`` selects the pure-jnp oracle instead —
+    the fallback when Pallas is unavailable).
     """
-    if r is not None:
+    bound = r is not None
+    if bound:
         r = jnp.asarray(r, jnp.float32)
 
     def policy(key, q, arrivals, mu, e, aux, scalar):
         del key, e
-        if r is None:
-            _, wpue, r_t = aux
-        else:
+        if bound:
             wpue, r_t = aux[1], r
+        else:
+            _, wpue, r_t = aux
         return gmsa_dispatch(
             q, arrivals, mu, None, scalar,
             impl=impl, r=r_t, wpue=wpue, p_it=p_it, interpret=interpret,
         )
 
-    policy.consumes_key = False
-    policy.wants_wpue = True
-    policy.wants_r = r is None
-    policy.static_r = r is not None
-    return policy
+    return declare(policy, keyed=False, reads="wpue" if bound else "wpue_r",
+                   binds_r=bound)

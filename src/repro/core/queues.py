@@ -35,11 +35,6 @@ def total_backlog(q: Array) -> Array:
     return jnp.sum(q)
 
 
-def average_backlog(q: Array) -> Array:
-    """Per-(DC, type) mean backlog — the y-axis of the paper's Fig. 5(b)/6(b)."""
-    return jnp.mean(q)
-
-
 def lyapunov(q: Array) -> Array:
     """Quadratic Lyapunov function L(t) = 1/2 * sum_{i,k} Q_i^k(t)^2."""
     return 0.5 * jnp.sum(jnp.square(q))

@@ -12,27 +12,26 @@ replication is a ``jax.vmap`` over PRNG keys (the paper averages 1000 runs).
 Policies are closures with signature
 ``(key, q, arrivals, mu, e, aux, scalar) -> f`` so GMSA and every baseline
 share one engine; ``scalar`` carries a *traced* control parameter (GMSA's V)
-so parameter sweeps reuse one compilation.
+so parameter sweeps reuse one compilation. What the engine must know about
+a policy beyond that is its :class:`repro.core.policy.PolicySpec`:
 
-Perf notes (EXPERIMENTS.md §Perf wall-clock track):
-  * the (K,N,N)×(N,) energy matvec is hoisted out of the scan body and
-    computed for all T slots in one einsum — and it is *closed over* rather
-    than vmapped, so Monte-Carlo runs share it;
-  * policies that declare ``state_independent = True`` (DATA, RANDOM) are
-    evaluated for all slots in one vectorized pass outside the scan;
-  * policies that declare ``consumes_key = False`` (GMSA, JSQ, GREEDY —
-    anything that deletes its key) skip the per-slot PRNG split entirely;
-  * the per-slot body is then 4 fused elementwise/contraction ops.
+  * ``state_independent`` policies (DATA, RANDOM) are decided for all
+    slots in one vectorized pass outside the scan;
+  * key-free policies (``keyed=False``: GMSA, JSQ, GREEDY) skip the
+    per-slot PRNG split entirely;
+  * ``reads`` picks the aux operand: the bare data distribution, or
+    ``(data_dist, omega_t * pue_t)`` with the product hoisted out of the
+    scan body — how the fused Pallas dispatch
+    (:func:`repro.core.gmsa.make_kernel_policy`) sees raw per-slot prices —
+    or that pair plus the ratio tensor in force this slot, ``r_t`` sliced
+    from a time-varying ``(T, K, N, N)`` trace;
+  * a policy that ``binds_r`` raises when fed a time-varying trace instead
+    of silently dispatching on stale ratios.
 
-Policies that declare ``wants_wpue = True`` receive ``aux = (data_dist,
-omega_t * pue_t)`` instead of the bare distribution — the hook the fused
-Pallas dispatch path (:func:`repro.core.gmsa.make_kernel_policy`) uses to
-see raw per-slot prices; the product is hoisted out of the scan body.
-Policies that additionally declare ``wants_r = True`` get the per-slot
-ratio tensor appended — ``aux = (data_dist, wpue_t, r_t)`` — so the kernel
-dispatch path sees time-varying ``(T, K, N, N)`` ratio traces instead of a
-stale static binding; a policy marked ``static_r = True`` fed a
-time-varying trace raises instead of silently dispatching on stale ratios.
+The (K,N,N)×(N,) energy matvec is hoisted out of the scan body and computed
+for all T slots in one einsum, closed over rather than vmapped so
+Monte-Carlo runs share it; the per-slot body is then 4 fused
+elementwise/contraction ops.
 
 Monte-Carlo replication shards across devices when ``simulate_many`` is
 given a ``mesh`` (:func:`repro.distributed.mesh.runs_mesh`): the runs axis
@@ -43,13 +42,15 @@ single-device vmap at every device count.
 from __future__ import annotations
 
 import functools
-from typing import Callable, NamedTuple
+from collections import namedtuple
+from typing import Any, Callable, NamedTuple
 
 import jax
 import jax.numpy as jnp
 from jax import Array
 
 from repro.core.energy import HIGHEST
+from repro.core.policy import check_ratios, decide_all_slots, slot_aux, spec_of
 from repro.core.queues import queue_step
 from repro.telemetry.config import TelemetryConfig
 from repro.telemetry.config import enabled as _tel_enabled
@@ -87,6 +88,37 @@ class SimOutputs(NamedTuple):
     backlog_avg: Array    # (T,) mean backlog per (DC, type)
     q_final: Array        # (N, K)
     f_trace: Array        # (T, N, K) dispatch decisions
+
+
+class ScanCarry(NamedTuple):
+    """What the engines' scans carry from step to step; ``None`` if absent."""
+
+    q: Array                 # backlogs
+    key: Array | None = None    # PRNG chain (keyed policies only)
+    d: Array | None = None      # placement layout (controller)
+    r: Array | None = None      # ratio tensor in force (controller faults)
+    fired: Array | None = None  # a recovery re-placed this epoch
+    ring: Any = None            # telemetry event ring (controller TRACE)
+
+
+class SlotCols(NamedTuple):
+    """One slot's stacked scan outputs: :func:`slot_step`'s five columns,
+    then the ones an engine records only under some options."""
+
+    cost: Array
+    energy: Array
+    backlog_total: Array
+    backlog_avg: Array
+    f: Array
+    recovery_cost: Array | None = None  # controller fault path
+    recovery_gb: Array | None = None
+    q_site: Array | None = None         # telemetry: (N,) per-site backlog
+    site_cost: Array | None = None      # telemetry histograms (controller)
+
+
+#: ``simulate``'s per-slot scan operands, ``None`` where absent: ``f`` a
+#: state-independent policy's decisions, ``dist``/``r`` time-varying traces.
+_SlotXs = namedtuple("_SlotXs", "arrivals mu e_cost e_raw f dist wpue r")
 
 
 PolicyFn = Callable[..., Array]
@@ -197,100 +229,58 @@ def simulate(
 
     dd_varying = inputs.data_dist.ndim == 3                        # (T, K, N)
     r_varying = inputs.r.ndim == 4                              # (T, K, N, N)
-    uses_key = getattr(policy, "consumes_key", True)
-    wants_wpue = getattr(policy, "wants_wpue", False)
-    wants_r = getattr(policy, "wants_r", False)
-    if r_varying and getattr(policy, "static_r", False):
-        raise ValueError(
-            "policy binds a static (K, N, N) ratio tensor but inputs.r is "
-            "time-varying (T, K, N, N) — the kernel would silently dispatch "
-            "on stale ratios. Build it with make_kernel_policy(r=None) so "
-            "the per-slot r reaches the kernel through the policy aux."
-        )
-    if wants_r and not wants_wpue:
-        raise ValueError(
-            "wants_r policies must also declare wants_wpue: the aux "
-            "contract is (data_dist, wpue_t, r_t)"
-        )
-    wpue_all = inputs.omega * inputs.pue if wants_wpue else None
+    spec = spec_of(policy)
+    check_ratios(spec, r_varying)
+    wpue_all = inputs.omega * inputs.pue if spec.reads != "dist" else None
 
     f_all = None
-    if getattr(policy, "state_independent", False):
-        keys = jax.random.split(key, t_slots)
-
-        def call(kk, a, m, e, d, w, rr):
-            aux = d
-            if wants_wpue:
-                aux = (aux, w)
-            if wants_r:
-                aux = aux + (rr,)
-            with jax.named_scope(GMSA_DECIDE):
-                return policy(kk, q0, a, m, e, aux, scalar)
-
-        f_all = jax.vmap(
-            call,
-            in_axes=(0, 0, 0, 0, 0 if dd_varying else None,
-                     0 if wants_wpue else None,
-                     0 if r_varying else None),
-        )(keys, inputs.arrivals, inputs.mu, e_cost_all,
-          inputs.data_dist, wpue_all,
-          inputs.r if wants_r else None)                           # (T, N, K)
+    if spec.state_independent:
+        f_all = decide_all_slots(policy, spec, key, q0, inputs, e_cost_all,
+                                 wpue_all, scalar)                 # (T, N, K)
 
     # The PRNG key rides in the scan carry ONLY when the policy actually
     # consumes it — for key-ignoring policies the per-slot threefry split
     # (and the whole key chain) disappears from the compiled body.
-    keyed = f_all is None and uses_key
+    keyed = f_all is None and spec.keyed
     key0 = key   # signature filler for key-ignoring policies (never used)
 
     def slot(carry, xs):
-        q, key = carry if keyed else (carry, None)
-        if wants_r and r_varying:
-            xs, r_t = xs[:-1], xs[-1]
-        if wants_wpue:
-            xs, wpue_t = xs[:-1], xs[-1]
-        if dd_varying:
-            xs, aux = xs[:-1], xs[-1]
-        else:
-            aux = inputs.data_dist
-        if wants_wpue:
-            aux = (aux, wpue_t)
-        if wants_r:
-            aux = aux + ((r_t if r_varying else inputs.r),)
         if f_all is None:
-            arrivals, mu, e_cost, e_raw = xs
             if keyed:
-                key, sub = jax.random.split(key)
+                key, sub = jax.random.split(carry.key)
             else:
-                sub = key0
+                key, sub = None, key0
+            aux = slot_aux(
+                spec,
+                inputs.data_dist if xs.dist is None else xs.dist,
+                xs.wpue,
+                inputs.r if xs.r is None else xs.r,
+            )
             with jax.named_scope(GMSA_DECIDE):
-                f = policy(sub, q, arrivals, mu, e_cost, aux, scalar)
+                f = policy(sub, carry.q, xs.arrivals, xs.mu, xs.e_cost, aux,
+                           scalar)
         else:
-            arrivals, mu, e_cost, e_raw, f = xs
-        q_next, out = slot_step(q, f, arrivals, mu, e_cost, e_raw)
-        if tel_on:
-            out = out + (jnp.sum(q_next, axis=-1),)       # (N,) per-site q
-        return ((q_next, key) if keyed else q_next), out
+            key, f = None, xs.f
+        q_next, out = slot_step(carry.q, f, xs.arrivals, xs.mu, xs.e_cost,
+                                xs.e_raw)
+        q_site = jnp.sum(q_next, axis=-1) if tel_on else None  # (N,) per-site q
+        return ScanCarry(q_next, key), SlotCols(*out, q_site=q_site)
 
-    xs = (inputs.arrivals, inputs.mu, e_cost_all, e_raw_all)
-    if f_all is not None:
-        xs = xs + (f_all,)
-    if dd_varying:
-        xs = xs + (inputs.data_dist,)
-    if wants_wpue:
-        xs = xs + (wpue_all,)
-    if wants_r and r_varying:
-        xs = xs + (inputs.r,)
-    carry0 = (q0, key) if keyed else q0
+    xs = _SlotXs(
+        inputs.arrivals, inputs.mu, e_cost_all, e_raw_all, f=f_all,
+        dist=inputs.data_dist if dd_varying else None,
+        wpue=wpue_all,
+        r=inputs.r if spec.reads == "wpue_r" and r_varying else None,
+    )
+    carry0 = ScanCarry(q0, key if keyed else None)
     with jax.named_scope(GMSA_SCAN):
         final_carry, scan_outs = jax.lax.scan(slot, carry0, xs)
+    f_trace = scan_outs.f
+    outs = SimOutputs(scan_outs.cost, scan_outs.energy,
+                      scan_outs.backlog_total, scan_outs.backlog_avg,
+                      final_carry.q, f_trace)
     if tel_on:
-        (cost, energy, btot, bavg, f_trace, q_site) = scan_outs
-    else:
-        (cost, energy, btot, bavg, f_trace) = scan_outs
-    q_final = final_carry[0] if keyed else final_carry
-    outs = SimOutputs(cost, energy, btot, bavg, q_final, f_trace)
-    if tel_on:
-        metrics = {"q_site": q_site}
+        metrics = {"q_site": scan_outs.q_site}
         if _tel_hist(telemetry):
             # Per-site energy-cost distribution, derived post-scan from
             # the stacked dispatch trace (zero ops in the scan body): the

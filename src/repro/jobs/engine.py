@@ -43,14 +43,17 @@ compilation — the same perf structure as the base simulator.
 from __future__ import annotations
 
 import functools
+from collections import namedtuple
 from typing import Callable, NamedTuple
 
 import jax
 import jax.numpy as jnp
 from jax import Array
 
+from repro.core.policy import check_ratios, decide_all_slots, slot_aux, spec_of
 from repro.core.simulator import (
     PolicyFn,
+    ScanCarry,
     SimInputs,
     _energy_tables,
 )
@@ -94,15 +97,15 @@ def staged_slot_update(
     ret,
     arrivals: Array,
     mu_stages: Array,
-    returns_flow: bool,
-    returns_hedge: bool = False,
+    returns: str = "f",
 ) -> tuple[Array, Array, Array, Array]:
     """One slot of the staged engine: tandem flow + Eq. 1 for every stage.
 
-    ``ret`` is the policy's output — ``(f, in_stack)`` for ``returns_flow``
-    policies (the stage-aware scheduler already walked the within-slot flow
+    ``ret`` is the policy's output, of the kind its spec ``returns``
+    (:class:`repro.core.policy.PolicySpec`) — ``(f, in_stack)`` for
+    ``"flow"`` (the stage-aware scheduler already walked the within-slot flow
     via :func:`repro.jobs.scheduler.flow_step`), bare ``f`` otherwise (the
-    recursion is replayed here). ``returns_hedge`` policies append the
+    recursion is replayed here). ``"flow_hedge"`` policies append the
     (N, K, S) speculative-clone matrix ``g`` — the queues then drain at
     the first-completion boosted rates (:func:`hedged_mu`), cond-gated so
     hedge-free slots stay bit-identical. This is the SINGLE definition of
@@ -117,11 +120,11 @@ def staged_slot_update(
         Eq. 1's max) and the (K, S) per-stage inflows.
     """
     s_max = dag.s_max
-    if returns_hedge:
+    if returns == "flow_hedge":
         f, in_stack, g = ret
         acc = q + f * in_stack[None, :, :]                         # (N, K, S)
         mu_stages = hedged_mu(f, g, mu_stages)
-    elif returns_flow:
+    elif returns == "flow":
         f, in_stack = ret
         acc = q + f * in_stack[None, :, :]                         # (N, K, S)
     else:
@@ -227,12 +230,20 @@ def _hedge_bill(
     return compute_bill + wan_bill, hedge_gb, hedged_jobs
 
 
+#: ``simulate_staged``'s per-slot scan operands and outputs, ``None`` where
+#: absent (as in :func:`repro.core.simulator.simulate`; ``g`` is the clone
+#: matrix of hedging policies).
+_StagedXs = namedtuple("_StagedXs", "arrivals mu e_cost mu_stages wpue f dist r")
+_StagedCols = namedtuple("_StagedCols", "f acc in_stack g")
+
+
 class StagedOutputs(NamedTuple):
     """Per-slot traces of one staged run (leading runs axis under vmap).
 
-    The three hedge columns are all-zero for policies without the
-    ``returns_hedge`` contract (and on healthy fleets where the hedge
-    never fires), so downstream consumers need no feature detection.
+    The three hedge columns are all-zero for policies that return no clone
+    matrix (any spec but ``returns="flow_hedge"``) and on healthy fleets
+    where the hedge never fires, so downstream consumers need no feature
+    detection.
     """
 
     cost: Array           # (T,) per-slot stage-compute energy cost
@@ -269,7 +280,7 @@ def simulate_staged(
             or time-varying exactly as in ``simulate``.
         dag: the (K, S) stage chain.
         wan: WAN model pricing the inter-stage shuffle bytes.
-        policy: a staged policy (attribute ``staged = True``, signature
+        policy: a staged policy (spec ``staged=True``, signature
             ``(key, q(N,K,S), arrivals, mu, e, (data_dist, wpue), scalar)
             -> f(N,K,S)``) or any base simulator policy, which is wrapped
             by :func:`repro.jobs.scheduler.stage_oblivious` automatically.
@@ -327,76 +338,43 @@ def simulate_staged(
     ec_stage_all = e_cost_all[:, :, None, :] * dag.compute[None, :, :, None]
     er_stage_all = e_raw_all[:, :, None, :] * dag.compute[None, :, :, None]
 
-    pol = policy if getattr(policy, "staged", False) else stage_oblivious(policy)
-    uses_key = getattr(pol, "consumes_key", True)
-    returns_flow = getattr(pol, "returns_flow", False)
-    returns_hedge = getattr(pol, "returns_hedge", False)
+    pol = policy if spec_of(policy).staged else stage_oblivious(policy)
+    spec = spec_of(pol)
+    hedging = spec.returns == "flow_hedge"
     dd_varying = inputs.data_dist.ndim == 3                        # (T, K, N)
     r_varying = inputs.r.ndim == 4                              # (T, K, N, N)
-    wants_r = getattr(pol, "wants_r", False)
-    if r_varying and getattr(pol, "static_r", False):
-        raise ValueError(
-            "policy binds a static (K, N, N) ratio tensor but inputs.r is "
-            "time-varying (T, K, N, N) — the kernel would silently dispatch "
-            "on stale ratios. Build it with make_kernel_policy(r=None) so "
-            "the per-slot r reaches the kernel through the policy aux."
-        )
-
-    if returns_flow and getattr(pol, "state_independent", False):
-        raise ValueError(
-            "returns_flow policies are state-dependent by construction "
-            "(the exported inflows depend on the live backlog); do not "
-            "also mark them state_independent"
-        )
+    check_ratios(spec, r_varying)
 
     f_all = None
-    if getattr(pol, "state_independent", False):
-        keys = jax.random.split(key, t_slots)
+    if spec.state_independent:
+        f_all = decide_all_slots(pol, spec, key, q0, inputs, e_cost_all,
+                                 wpue_all, scalar)
 
-        def call(kk, a, m, e, d, w, rr):
-            aux = (d, w)
-            if wants_r:
-                aux = aux + (rr,)
-            return pol(kk, q0, a, m, e, aux, scalar)
-
-        f_all = jax.vmap(
-            call,
-            in_axes=(0, 0, 0, 0, 0 if dd_varying else None, 0,
-                     0 if r_varying else None),
-        )(keys, inputs.arrivals, inputs.mu, e_cost_all,
-          inputs.data_dist, wpue_all, inputs.r if wants_r else None)
-
-    keyed = f_all is None and uses_key
+    keyed = f_all is None and spec.keyed
     key0 = key   # for key-ignoring policies (signature filler, never used)
 
     def slot(carry, xs):
-        q, key = carry if keyed else (carry, None)
-        if wants_r and r_varying:
-            xs, r_t = xs[:-1], xs[-1]
-        if dd_varying:
-            xs, dd_t = xs[:-1], xs[-1]
-        else:
-            dd_t = inputs.data_dist
-        arrivals, mu, e_cost, mu_stages, wpue_t = xs[:5]
-        rest = xs[5:]
         if f_all is None:
             if keyed:
-                key, sub = jax.random.split(key)
+                key, sub = jax.random.split(carry.key)
             else:
-                sub = key0   # key-ignoring policy: no per-slot split
-            aux = (dd_t, wpue_t)
-            if wants_r:
-                aux = aux + ((r_t if r_varying else inputs.r),)
-            ret = pol(sub, q, arrivals, mu, e_cost, aux, scalar)
+                key, sub = None, key0   # key-ignoring policy: no split
+            aux = slot_aux(
+                spec,
+                inputs.data_dist if xs.dist is None else xs.dist,
+                xs.wpue,
+                inputs.r if xs.r is None else xs.r,
+            )
+            ret = pol(sub, carry.q, xs.arrivals, xs.mu, xs.e_cost, aux, scalar)
         else:
-            (ret,) = rest
+            key, ret = None, xs.f
 
         # Within-slot tandem flow — the only genuinely sequential part,
         # stripped to its recursion via the shared :func:`staged_slot_update`
         # (acc = Q + f·F is the inside of Eq. 1's max — exactly
         # ``slot_step``'s ``q + fa``; completions min(acc, mu) seed the next
         # stage). Policies that walked this exact chain already
-        # (``returns_flow = True`` — the stage-aware scheduler's lookahead
+        # (``returns="flow"`` — the stage-aware scheduler's lookahead
         # shares flow_step's definition) export the per-stage inflows and
         # the recursion is skipped entirely. Everything derivable from
         # (f, acc, ins) — cost/energy accrual, backlogs, source mixes,
@@ -404,29 +382,20 @@ def simulate_staged(
         # vectorized over all T slots AFTER the scan, keeping the per-slot
         # body minimal.
         q_next, f, acc, in_stack = staged_slot_update(
-            dag, q, ret, arrivals, mu_stages, returns_flow, returns_hedge
+            dag, carry.q, ret, xs.arrivals, xs.mu_stages, spec.returns
         )
+        g = ret[2] if hedging else None
+        return ScanCarry(q_next, key), _StagedCols(f, acc, in_stack, g)
 
-        out = (f, acc, in_stack)
-        if returns_hedge:
-            out = out + (ret[2],)
-        return ((q_next, key) if keyed else q_next), out
-
-    xs = (inputs.arrivals, inputs.mu, e_cost_all, mu_stage_all, wpue_all)
-    if f_all is not None:
-        xs = xs + (f_all,)
-    if dd_varying:
-        xs = xs + (inputs.data_dist,)
-    if wants_r and r_varying:
-        xs = xs + (inputs.r,)
-    carry0 = (q0, key) if keyed else q0
+    xs = _StagedXs(
+        inputs.arrivals, inputs.mu, e_cost_all, mu_stage_all, wpue_all,
+        f=f_all, dist=inputs.data_dist if dd_varying else None,
+        r=inputs.r if spec.reads == "wpue_r" and r_varying else None,
+    )
+    carry0 = ScanCarry(q0, key if keyed else None)
     final_carry, scan_outs = jax.lax.scan(slot, carry0, xs)
-    if returns_hedge:
-        f_trace, acc_all, in_all, g_all = scan_outs
-    else:
-        f_trace, acc_all, in_all = scan_outs
-        g_all = None
-    q_final = final_carry[0] if keyed else final_carry
+    f_trace, acc_all, in_all, g_all = scan_outs
+    q_final = final_carry.q
 
     # Everything the scan body did NOT compute, recovered vectorized over
     # all T slots from (f, acc, ins) — the expressions are ``slot_step``'s
@@ -443,7 +412,7 @@ def simulate_staged(
                    axis=(1, 2, 3))                                 # (T,)
     energy = jnp.sum(fa_all * er_stage_all.transpose(0, 3, 1, 2),
                      axis=(1, 2, 3))
-    if returns_hedge:
+    if hedging:
         # The carried queues drained at the first-completion boosted
         # rates (cond-gated in the scan body); the vectorized replay
         # applies the same boost unconditionally — slots without a
@@ -488,7 +457,7 @@ def simulate_staged(
         )
         wan_c = wan_c + sur_c
         wan_e = wan_e + sur_e
-    if returns_hedge:
+    if hedging:
         hedge_cost, hedge_gb, hedged_jobs = _hedge_bill(
             dag, wan, g_all, acc_all, mu_stage_all, mu_eff_all,
             ec_stage_all, src_all, wpue_all,

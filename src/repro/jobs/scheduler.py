@@ -35,10 +35,13 @@ dispatch the benchmarks compare against.
 
 from __future__ import annotations
 
+import dataclasses
+
 import jax.numpy as jnp
 from jax import Array
 
 from repro.core.gmsa import drift_plus_penalty_scores
+from repro.core.policy import declare, slot_aux, spec_of, split_aux
 from repro.jobs.dag import StageDag
 from repro.placement.wan import WanModel, expected_pull
 
@@ -180,7 +183,7 @@ def make_staged_policy(dag: StageDag, wan: WanModel, pin_map: bool = True,
             (:func:`hedge_clone_choice`), the within-slot flow walk runs
             on the first-completion boosted rates, and the policy
             additionally returns the (N, K, S) clone matrix
-            (``returns_hedge`` contract of ``simulate_staged``).
+            (``returns="flow_hedge"`` in its spec).
     """
 
     def policy(key, q, arrivals, mu, e, aux, scalar):
@@ -228,18 +231,15 @@ def make_staged_policy(dag: StageDag, wan: WanModel, pin_map: bool = True,
         # The lookahead already walked the exact within-slot flow the
         # engine would re-derive (flow_step is the shared definition), so
         # export the per-stage inflows and let the engine skip its own
-        # recursion (``returns_flow`` contract of ``simulate_staged``).
+        # recursion (``returns="flow"`` in the policy's spec).
         f = jnp.stack(cols, axis=-1)
         in_stack = jnp.stack(ins, axis=-1)                         # (K, S)
         if hedge is not None:
             return f, in_stack, jnp.stack(clones, axis=-1)
         return f, in_stack
 
-    policy.staged = True
-    policy.consumes_key = False
-    policy.returns_flow = True
-    policy.returns_hedge = hedge is not None
-    return policy
+    return declare(policy, keyed=False, staged=True, reads="wpue",
+                   returns="flow" if hedge is None else "flow_hedge")
 
 
 def staged_dispatch_fn(dag: StageDag, wan: WanModel, v: float,
@@ -251,11 +251,7 @@ def staged_dispatch_fn(dag: StageDag, wan: WanModel, v: float,
         del scalar
         return base(key, q, arrivals, mu, e, aux, v)
 
-    policy.staged = True
-    policy.consumes_key = False
-    policy.returns_flow = True
-    policy.returns_hedge = hedge is not None
-    return policy
+    return declare(policy, spec_of(base))
 
 
 def stage_oblivious(policy, pin_map: bool = False):
@@ -270,31 +266,27 @@ def stage_oblivious(policy, pin_map: bool = False):
 
     Args:
         policy: any base policy ``(key, q(N,K), arrivals, mu, e, aux,
-            scalar) -> f(N,K)``. Policies declaring ``wants_wpue = True``
-            (the Pallas-kernel dispatch of
-            :func:`repro.core.gmsa.make_kernel_policy`) receive the full
-            ``(data_dist, omega*PUE)`` aux pair, exactly as
-            :func:`repro.core.simulator.simulate` hands it to them — the
-            staged engines always carry ``wpue``, so the fleet-scale kernel
-            path composes with stage-structured queues unchanged. Policies
-            additionally declaring ``wants_r = True`` (the carried-r kernel
-            variant) get the engine's ``(data_dist, wpue, r_t)`` triple
-            passed through verbatim.
+            scalar) -> f(N,K)``. The adapter reads the staged engines'
+            ``(data_dist, wpue)`` aux — plus ``r_t`` when the base policy
+            ``reads="wpue_r"`` (the carried-r kernel variant) — and hands
+            the base policy the aux its own spec reads, exactly as
+            :func:`repro.core.simulator.simulate` would: the fleet-scale
+            kernel path of :func:`repro.core.gmsa.make_kernel_policy`
+            composes with stage-structured queues unchanged. Key use,
+            state independence and a bound ratio tensor carry over.
         pin_map: override stage 0 with data-local map placement (used when
             benchmarking against stage-aware policies under the same
             data-local-map premise; keep False for exact base semantics).
     """
-    wants_wpue = getattr(policy, "wants_wpue", False)
-    wants_r = getattr(policy, "wants_r", False)
+    base = spec_of(policy)
+    spec = dataclasses.replace(
+        base, staged=True,
+        reads="wpue_r" if base.reads == "wpue_r" else "wpue",
+    )
 
     def staged(key, q, arrivals, mu, e, aux, scalar):
-        data_dist = aux[0]
-        if wants_r:
-            base_aux = aux                 # (data_dist, wpue, r_t) verbatim
-        elif wants_wpue:
-            base_aux = (data_dist, aux[1])
-        else:
-            base_aux = data_dist
+        data_dist, wpue, r_t = split_aux(spec, aux)
+        base_aux = slot_aux(base, data_dist, wpue, r_t)
         q_total = jnp.sum(q, axis=-1)                              # (N, K)
         f_base = policy(key, q_total, arrivals, mu, e, base_aux, scalar)
         f = jnp.broadcast_to(f_base[:, :, None], q.shape)
@@ -304,9 +296,4 @@ def stage_oblivious(policy, pin_map: bool = False):
             )
         return f
 
-    staged.staged = True
-    staged.state_independent = getattr(policy, "state_independent", False)
-    staged.consumes_key = getattr(policy, "consumes_key", True)
-    staged.wants_r = wants_r
-    staged.static_r = getattr(policy, "static_r", False)
-    return staged
+    return declare(staged, spec)

@@ -2,7 +2,7 @@
 
 ``[audio]`` / ``[vlm]`` architecture entries specify the transformer backbone
 only; the modality frontend supplies *precomputed* frame/patch embeddings via
-``input_specs()``. These helpers define the stub geometry the launchers and
+``input_specs()``. These constants define the stub geometry the launchers and
 dry-run share.
 
 * vision (InternVL2): 256 image tokens per sample, 1024-dim patch embeddings
@@ -14,24 +14,6 @@ dry-run share.
 
 from __future__ import annotations
 
-import dataclasses
-
 VISION_STUB_DIM = 1024
 VISION_TOKENS = 256
 AUDIO_STUB_DIM = 512
-
-
-@dataclasses.dataclass(frozen=True)
-class FrontendSpec:
-    kind: str          # "vision" | "audio"
-    stub_dim: int
-    prefix_tokens: int # embeddings prepended per sample (0 = replaces tokens)
-
-
-def frontend_spec(kind: str, seq_len: int) -> FrontendSpec | None:
-    if kind == "vision":
-        return FrontendSpec("vision", VISION_STUB_DIM, VISION_TOKENS)
-    if kind == "audio":
-        # Encoder consumes frame embeddings only; no token prefix.
-        return FrontendSpec("audio", AUDIO_STUB_DIM, 0)
-    return None

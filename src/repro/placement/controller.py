@@ -41,6 +41,7 @@ from __future__ import annotations
 
 import dataclasses
 import functools
+from collections import namedtuple
 from typing import Callable, NamedTuple
 
 import jax
@@ -49,9 +50,12 @@ from jax import Array
 
 from repro.checkpoint.fault import drop_site_mask
 from repro.core.iridium import make_allocation_rebuilder
+from repro.core.policy import check_ratios, slot_aux, spec_of
 from repro.core.simulator import (
     PolicyFn,
+    ScanCarry,
     SimInputs,
+    SlotCols,
     energy_row,
     energy_tables,
     slot_step,
@@ -244,6 +248,22 @@ class PlacedOutputs(NamedTuple):
                            # unless cfg.io_coupling)
 
 
+#: The epoch and slot scans' operands, ``None`` where absent: ``keys`` for
+#: state-independent policies, ``alive``/``alive_prev`` (and, in the slot
+#: scan, everything after them) on the site-loss path, the indices and ``t``
+#: for telemetry TRACE, ``wpue`` for price-reading policies without faults.
+_EpochXs = namedtuple(
+    "_EpochXs", "arrivals mu omega pue sizes_gb ingest first keys alive "
+    "alive_prev link_health epoch_idx slot_idx", defaults=(None,) * 6)
+_SlotXs = namedtuple(
+    "_SlotXs", "arrivals mu e_cost e_raw key wpue alive alive_prev omega pue "
+    "link_health mu_raw t", defaults=(None,) * 9)
+#: One epoch's stacked outputs: its slots' columns, then the audit trail.
+_EpochOut = namedtuple(
+    "_EpochOut", "slots placements r_trace wan_cost wan_energy wan_gb "
+    "wan_latency_s sync_cost mu_scale")
+
+
 @functools.partial(
     jax.jit, static_argnames=("policy", "rule", "cfg", "telemetry")
 )
@@ -413,20 +433,13 @@ def simulate_placed(
     # Match ``simulate``'s PRNG stream exactly on both of its policy paths:
     # state-independent policies consume split(key, T)[t] per slot (the
     # precomputed-vmap path), everything else splits the carried key —
-    # except key-ignoring policies (``consumes_key = False``: GMSA, JSQ,
-    # GREEDY), whose per-slot threefry split is skipped entirely, exactly
-    # as ``simulate`` skips it.
-    state_ind = getattr(policy, "state_independent", False)
-    uses_key = getattr(policy, "consumes_key", True)
-    wants_wpue = getattr(policy, "wants_wpue", False)
-    wants_r = getattr(policy, "wants_r", False)
-    if getattr(policy, "static_r", False):
-        raise ValueError(
-            "the controller re-derives r at every epoch boundary (and "
-            "recovery edge) — a policy binding a static ratio tensor would "
-            "dispatch on stale ratios; build it with "
-            "make_kernel_policy(r=None) so the carried r reaches the kernel."
-        )
+    # except key-free policies (``keyed=False``: GMSA, JSQ, GREEDY), whose
+    # per-slot threefry split is skipped entirely, exactly as ``simulate``
+    # skips it. The controller re-derives r at every epoch boundary (and
+    # recovery edge), so a policy binding a static r is always stale.
+    spec = spec_of(policy)
+    check_ratios(spec, r_varying=True)
+    state_ind = spec.state_independent
     keys_ep = ep(jax.random.split(key, t_slots)) if state_ind else None
 
     q0 = jnp.zeros((n, k_types), jnp.float32)
@@ -457,24 +470,13 @@ def simulate_placed(
         slow0 = io_slow(d0)
 
     def epoch(carry, xs):
-        if tel_trace:
-            q, key, d, ring = carry
-        else:
-            q, key, d = carry
-        rest = xs[7:]
-        arr_e, mu_e, om_e, pu_e, size_e, ing_e, is_first = xs[:7]
-        if state_ind:
-            keys_e, rest = rest[0], rest[1:]
-        if tel_trace:
-            e_idx, t_e = rest[-2], rest[-1]
-            rest = rest[:-2]
-        if linky:
-            lh_e, rest = rest[-1], rest[:-1]
+        q, key, d, ring = carry.q, carry.key, carry.d, carry.ring
+        arr_e, mu_e, om_e, pu_e = xs.arrivals, xs.mu, xs.omega, xs.pue
+        size_e, ing_e, is_first = xs.sizes_gb, xs.ingest, xs.first
         if faulty:
-            alive_e, alive_prev_e = rest
             # Aliveness *entering* the epoch drives the boundary decision;
             # deaths inside the epoch are handled by the slot-level edges.
-            alive_b = alive_prev_e[0]                                 # (N,)
+            alive_b = xs.alive_prev[0]                                # (N,)
             any_dead_b = jnp.any(alive_b < 0.5)
 
         # -- slow timescale: drift, observe, re-place, pay the WAN bill.
@@ -538,7 +540,7 @@ def simulate_placed(
             # Degraded links enter as an additive premium on the fused
             # bill (exactly 0.0 on all-alive links) and stretch the
             # bottleneck latency of the boundary move.
-            lh_b = lh_e[0]
+            lh_b = xs.link_health[0]
             sur_c, sur_e = degraded_surcharge(
                 d_drift, d_new, size_e, wan, om_e[0], pu_e[0], lh_b
             )
@@ -562,16 +564,16 @@ def simulate_placed(
             churn = 0.5 * jnp.sum(jnp.abs(d_new - d_drift))
             tgt_churn = 0.5 * jnp.sum(jnp.abs(target - d_drift))
             ring = ring_push(
-                ring, jnp.bool_(True), e_idx * w, EV_EPOCH,
+                ring, jnp.bool_(True), xs.epoch_idx * w, EV_EPOCH,
                 (wan_gb, wan_c, sync_c, churn,
                  churn / jnp.maximum(tgt_churn, _EPS),
-                 e_idx.astype(jnp.float32)),
+                 xs.epoch_idx.astype(jnp.float32)),
             )
             if ingest is not None and faulty:
                 ring = ring_push(
                     ring,
                     jnp.logical_and(any_dead_b, jnp.logical_not(is_first)),
-                    e_idx * w, EV_INGEST_REDIRECT,
+                    xs.epoch_idx * w, EV_INGEST_REDIRECT,
                     (jnp.sum(ing_e * (1.0 - alive_b)[None, :]),
                      jnp.float32(n) - jnp.sum(alive_b)),
                 )
@@ -596,32 +598,18 @@ def simulate_placed(
         e_cost, e_raw = energy_tables(r_e, wpue_e, pu_e, p_it)
 
         def slot(carry2, xs2):
-            if faulty:
-                if tel_trace:
-                    q2, key2, d_c, r_c, fired, ring2 = carry2
-                else:
-                    q2, key2, d_c, r_c, fired = carry2
-            else:
-                q2, key2 = carry2
-            arrivals, mu, ec, er = xs2[:4]
-            rest2 = xs2[4:]
+            q2, key2, d_c, r_c, fired, ring2 = carry2
+            arrivals, mu, ec, er = xs2.arrivals, xs2.mu, xs2.e_cost, xs2.e_raw
             if state_ind:
-                sub, rest2 = rest2[0], rest2[1:]
-            elif uses_key:
+                sub = xs2.key
+            elif spec.keyed:
                 key2, sub = jax.random.split(key2)
             else:
-                sub = key2   # key-ignoring policy: no per-slot split
-            if wants_wpue and not faulty:
-                wpue_t, rest2 = rest2[0], rest2[1:]
-            aux = d_new
+                sub = key2   # key-free policy: no per-slot split
+            wpue_t, rec_cost, rec_gb = xs2.wpue, None, None
             if faulty:
-                if tel_trace:
-                    t_t, rest2 = rest2[-1], rest2[:-1]
-                if cfg.io_coupling:
-                    mu_raw_t, rest2 = rest2[-1], rest2[:-1]
-                if linky:
-                    lh_t, rest2 = rest2[-1], rest2[:-1]
-                alive_t, alive_prev_t, om_t, pu_t = rest2
+                alive_t, alive_prev_t = xs2.alive, xs2.alive_prev
+                om_t, pu_t, lh_t = xs2.omega, xs2.pue, xs2.link_health
                 died = alive_prev_t * (1.0 - alive_t)                 # (N,)
                 any_died = jnp.any(died > 0.5)
                 any_dead = jnp.any(alive_t < 0.5)
@@ -704,7 +692,7 @@ def simulate_placed(
                     # (maybe) ran: a masked ring write, so the no-edge slot
                     # costs a handful of fused selects and writes nothing.
                     ring2 = ring_push(
-                        ring2, any_died, t_t, EV_RECOVERY,
+                        ring2, any_died, xs2.t, EV_RECOVERY,
                         (rec_gb, rec_cost, jnp.sum(died),
                          jnp.argmax(died).astype(jnp.float32)),
                     )
@@ -715,7 +703,7 @@ def simulate_placed(
                     # leaves the ring bitwise untouched.
                     revived = alive_t * (1.0 - alive_prev_t)
                     ring2 = ring_push(
-                        ring2, jnp.any(revived > 0.5), t_t, EV_REPAIR,
+                        ring2, jnp.any(revived > 0.5), xs2.t, EV_REPAIR,
                         (jnp.sum(revived),
                          jnp.argmax(revived).astype(jnp.float32)),
                     )
@@ -740,20 +728,16 @@ def simulate_placed(
                         s = io_slow(dc) / slow0
                         if not cfg.io_per_reader:
                             s = s[:, None]
-                        return mu_raw_t * s * alive_t[:, None]
+                        return xs2.mu_raw * s * alive_t[:, None]
 
                     mu = jax.lax.cond(
                         fired, _io_rescale, lambda dc: mu, d_c
                     )
-                aux = d_c
-            if wants_wpue:
-                # The kernel-dispatch aux contract: raw per-slot prices,
-                # and (wants_r) the ratio tensor actually in force — the
-                # carried r_c on the fault path (recovery re-places mid-
-                # epoch), the epoch rebuild r_e otherwise.
-                aux = (aux, wpue_t)
-            if wants_r:
-                aux = aux + ((r_c if faulty else r_e),)
+            # The layout, prices and ratio tensor actually in force: the
+            # carried d_c/r_c on the fault path (recovery re-places mid-
+            # epoch), the epoch's d_new/r_e otherwise.
+            aux = slot_aux(spec, d_c if faulty else d_new, wpue_t,
+                           r_c if faulty else r_e)
             with jax.named_scope(GMSA_DECIDE):
                 f = policy(sub, q2, arrivals, mu, ec, aux, scalar)
             if faulty:
@@ -763,108 +747,85 @@ def simulate_placed(
                 f_m = _survivor_renorm(f * alive_t[:, None], f_fb, axis=0)
                 f = jnp.where(any_dead, f_m, f)
             q_next, out = slot_step(q2, f, arrivals, mu, ec, er)
+            q_site = site_cost = None
             if tel_on:
-                tel_out = (jnp.sum(q_next, axis=-1),)     # (N,) per-site q
+                q_site = jnp.sum(q_next, axis=-1)         # (N,) per-site q
                 if tel_hist:
                     # Per-site slice of the bill ``slot_step`` just summed
                     # — recorded in-scan because recovery epochs rewrite
                     # the energy rows mid-epoch (``ec`` is cond-carried,
                     # not reconstructible from the epoch tables post-scan).
-                    tel_out = tel_out + (
-                        jnp.sum(f * arrivals[None, :] * ec.T, axis=1),
-                    )
-            else:
-                tel_out = ()
-            if faulty:
-                carry_next = (q_next, key2, d_c, r_c, fired)
-                if tel_trace:
-                    carry_next = carry_next + (ring2,)
-                return carry_next, out + (rec_cost, rec_gb) + tel_out
-            return (q_next, key2), out + tel_out
+                    site_cost = jnp.sum(f * arrivals[None, :] * ec.T, axis=1)
+            return (ScanCarry(q_next, key2, d_c, r_c, fired, ring2),
+                    SlotCols(*out, rec_cost, rec_gb, q_site, site_cost))
 
-        slot_xs = (arr_e, mu_e, e_cost, e_raw)
-        if state_ind:
-            slot_xs = slot_xs + (keys_e,)
-        if wants_wpue and not faulty:
-            slot_xs = slot_xs + (wpue_e,)
+        slot_xs = _SlotXs(arr_e, mu_e, e_cost, e_raw, key=xs.keys)
+        carry0 = ScanCarry(q, key)
         if faulty:
-            slot_xs = slot_xs + (alive_e, alive_prev_e, om_e, pu_e)
-            if linky:
-                slot_xs = slot_xs + (lh_e,)
-            if cfg.io_coupling:
-                slot_xs = slot_xs + (mu_e_raw,)
-            if tel_trace:
-                slot_xs = slot_xs + (t_e,)
-            carry0 = (q, key, d_new, r_e, jnp.bool_(False))
-            if tel_trace:
-                carry0 = carry0 + (ring,)
-            with jax.named_scope(GMSA_SCAN):
-                carry_out, slot_outs = jax.lax.scan(slot, carry0, slot_xs)
-            q, key, d_carry = carry_out[:3]
-            if tel_trace:
-                ring = carry_out[-1]
-        else:
-            with jax.named_scope(GMSA_SCAN):
-                (q, key), slot_outs = jax.lax.scan(slot, (q, key), slot_xs)
-            d_carry = d_new
-        epoch_out = slot_outs + (d_new, r_e, wan_c, wan_e, wan_gb, wan_lat,
-                                 sync_c, scale_e)
-        carry_out = (q, key, d_carry)
-        if tel_trace:
-            carry_out = carry_out + (ring,)
-        return carry_out, epoch_out
+            slot_xs = slot_xs._replace(
+                alive=xs.alive, alive_prev=xs.alive_prev,
+                omega=om_e, pue=pu_e, link_health=xs.link_health,
+                mu_raw=mu_e_raw if cfg.io_coupling else None, t=xs.slot_idx,
+            )
+            carry0 = ScanCarry(q, key, d_new, r_e, jnp.bool_(False), ring)
+        elif spec.reads != "dist":
+            slot_xs = slot_xs._replace(wpue=wpue_e)
+        with jax.named_scope(GMSA_SCAN):
+            slot_carry, slot_outs = jax.lax.scan(slot, carry0, slot_xs)
+        q, key = slot_carry.q, slot_carry.key
+        d_carry = slot_carry.d if faulty else d_new
+        ring = slot_carry.ring if faulty else ring
+        epoch_out = _EpochOut(slot_outs, d_new, r_e, wan_c, wan_e, wan_gb,
+                              wan_lat, sync_c, scale_e)
+        return ScanCarry(q, key, d_carry, ring=ring), epoch_out
 
-    xs = (arr_ep, mu_ep, om_ep, pu_ep, sizes_gb,
-          ingest if ingest is not None else jnp.zeros((n_epochs, k_types, n)),
-          first)
-    if state_ind:
-        xs = xs + (keys_ep,)
-    if faulty:
-        xs = xs + (ep(alive), ep(alive_prev))
-    if linky:
-        xs = xs + (ep(link_health),)
-    carry_init = (q0, key, d0)
+    xs = _EpochXs(
+        arr_ep, mu_ep, om_ep, pu_ep, sizes_gb,
+        ingest if ingest is not None else jnp.zeros((n_epochs, k_types, n)),
+        first, keys=keys_ep,
+        alive=ep(alive) if faulty else None,
+        alive_prev=ep(alive_prev) if faulty else None,
+        link_health=ep(link_health) if linky else None,
+    )
+    ring0 = None
     if tel_trace:
-        xs = xs + (jnp.arange(n_epochs, dtype=jnp.int32),
-                   jnp.arange(t_slots, dtype=jnp.int32).reshape(n_epochs, w))
-        carry_init = carry_init + (ring_init(telemetry.capacity),)
+        xs = xs._replace(
+            epoch_idx=jnp.arange(n_epochs, dtype=jnp.int32),
+            slot_idx=jnp.arange(t_slots, dtype=jnp.int32).reshape(n_epochs, w),
+        )
+        ring0 = ring_init(telemetry.capacity)
     with jax.named_scope(PLACED_EPOCHS):
-        carry_final, outs = jax.lax.scan(epoch, carry_init, xs)
-    q_final = carry_final[0]
-    if tel_trace:
-        ring_out = carry_final[-1]
-    # Per-slot scan columns lead; the epoch-level audit trail follows.
-    n_slot_cols = (5 + (2 if faulty else 0) + (1 if tel_on else 0)
-                   + (1 if tel_hist else 0))
-    slot_cols = outs[:n_slot_cols]
-    (d_tr, r_tr, wc, we, wgb, wlat, sc, msc) = outs[n_slot_cols:]
-    (cost, energy, btot, bavg, f_trace) = slot_cols[:5]
+        carry_final, outs = jax.lax.scan(
+            epoch, ScanCarry(q0, key, d0, ring=ring0), xs
+        )
+    cols = outs.slots
     if faulty:
-        rec_cost, rec_gb = slot_cols[5:7]
+        rec_cost, rec_gb = cols.recovery_cost, cols.recovery_gb
     else:
         rec_cost = jnp.zeros((n_epochs, w), jnp.float32)
         rec_gb = jnp.zeros((n_epochs, w), jnp.float32)
     flat = lambda x: x.reshape((t_slots,) + x.shape[2:])
     placed = PlacedOutputs(
-        cost=flat(cost), energy=flat(energy),
-        backlog_total=flat(btot), backlog_avg=flat(bavg),
-        q_final=q_final, f_trace=flat(f_trace),
-        placements=d_tr, r_trace=r_tr,
-        wan_cost=wc, wan_energy=we, wan_gb=wgb, wan_latency_s=wlat,
-        sync_cost=sc,
+        cost=flat(cols.cost), energy=flat(cols.energy),
+        backlog_total=flat(cols.backlog_total),
+        backlog_avg=flat(cols.backlog_avg),
+        q_final=carry_final.q, f_trace=flat(cols.f),
+        placements=outs.placements, r_trace=outs.r_trace,
+        wan_cost=outs.wan_cost, wan_energy=outs.wan_energy,
+        wan_gb=outs.wan_gb, wan_latency_s=outs.wan_latency_s,
+        sync_cost=outs.sync_cost,
         recovery_cost=flat(rec_cost), recovery_gb=flat(rec_gb),
-        mu_scale=msc,
+        mu_scale=outs.mu_scale,
     )
     if tel_on:
-        q_site = slot_cols[-2] if tel_hist else slot_cols[-1]  # (E, W, N)
-        metrics = {"q_site": flat(q_site)}
+        metrics = {"q_site": flat(cols.q_site)}                # (T, N)
         if tel_hist:
-            site_cost = flat(slot_cols[-1])                    # (T, N)
+            site_cost = flat(cols.site_cost)                   # (T, N)
             metrics["site_cost_hist"] = hist_series(
                 telemetry.hist, site_cost, axis=0
             )                                                  # (N, B)
         return placed, TelemetryFrame(
-            ring=ring_out if tel_trace else ring_init(1),
+            ring=carry_final.ring if tel_trace else ring_init(1),
             metrics=metrics,
         )
     return placed

@@ -50,6 +50,7 @@ from jax import Array
 
 from repro.configs.base import ModelConfig
 from repro.core.gmsa import make_kernel_policy
+from repro.core.policy import spec_of
 from repro.core.simulator import SimInputs, _energy_tables
 from repro.jobs.dag import StageDag, chain_dag
 from repro.jobs.engine import (
@@ -377,10 +378,10 @@ class FleetEngine:
             self.link_health = link_health
         self.p_it = self.scenario.inputs.p_it
         self.policy = serve_policy(fcfg, self.scenario)
-        if getattr(self.policy, "consumes_key", True):
+        if spec_of(self.policy).keyed:
             raise ValueError(
                 "FleetEngine dispatch policies must be key-free "
-                "(consumes_key=False) so the serving loop carries no PRNG "
+                "(keyed=False) so the serving loop carries no PRNG "
                 "chain — both built-in dispatch impls are"
             )
         self.alive = None
@@ -400,8 +401,8 @@ class FleetEngine:
     def _make_step(self, faulty: bool):
         pol = self.policy
         dag = self.scenario.dag
-        returns_flow = getattr(pol, "returns_flow", False)
-        returns_hedge = getattr(pol, "returns_hedge", False)
+        returns = spec_of(pol).returns
+        hedging = returns == "flow_hedge"
         key0 = jax.random.key(0)   # signature filler: key-free policies only
         hist_on = self._hist_on
         spec = self.telemetry.hist if hist_on else None
@@ -409,9 +410,9 @@ class FleetEngine:
         def core(q, arrivals, mu, e_cost, mu_stages, dd_t, wpue_t, v):
             ret = pol(key0, q, arrivals, mu, e_cost, (dd_t, wpue_t), v)
             q_next, f, acc, in_stack = staged_slot_update(
-                dag, q, ret, arrivals, mu_stages, returns_flow, returns_hedge
+                dag, q, ret, arrivals, mu_stages, returns
             )
-            if returns_hedge:
+            if hedging:
                 # Queues drained at the first-completion boosted rates;
                 # ``done`` must drain the same flow, so hand the boosted
                 # rates (and the clone matrix, for the honest post-run
@@ -437,7 +438,7 @@ class FleetEngine:
                     )
                     done = jnp.minimum(acc, mu_eff)
                     out = (q_next, f, acc, in_stack, done, jnp.float32(0.0))
-                    if returns_hedge:
+                    if hedging:
                         out = out + (g,)
                     return out
                 return step
@@ -451,7 +452,7 @@ class FleetEngine:
                 done = jnp.minimum(acc, mu_eff)
                 age, hist = clock(age, hist, arrivals, done)
                 out = (q_next, f, acc, in_stack, done, jnp.float32(0.0))
-                if returns_hedge:
+                if hedging:
                     out = out + (g,)
                 return out + (age, hist)
             return step
@@ -492,7 +493,7 @@ class FleetEngine:
             )
             done = jnp.minimum(acc, mu_eff)
             out = (q_next, f, acc, in_stack, done, jnp.sum(burst))
-            if returns_hedge:
+            if hedging:
                 out = out + (g,)
             if hist_on:
                 age, hist = clock(tel[0], tel[1], admitted0, done)
@@ -610,7 +611,7 @@ class FleetEngine:
 
         q = jnp.zeros((n, k, s_max), jnp.float32)
         hist_on = self._hist_on
-        hedging = getattr(self.policy, "returns_hedge", False)
+        hedging = spec_of(self.policy).returns == "flow_hedge"
         if hist_on:
             # Per-class FIFO sojourn clock: the age ring is bounded by the
             # horizon (no request can wait longer than the run).

@@ -39,26 +39,6 @@ def bandwidth_draw(
     return up, down
 
 
-def bandwidth_trace(
-    key: Array,
-    t_slots: int,
-    n_sites: int,
-    lo: float = BW_MIN_GBPS,
-    hi: float = BW_MAX_GBPS,
-    wobble: float = 0.15,
-) -> tuple[Array, Array]:
-    """Time-varying bandwidths: static draw modulated by bounded noise.
-
-    Models "other applications sharing the same links" (paper Sec. II):
-    available bandwidth wobbles by ±``wobble`` around the provisioned value.
-    """
-    k_static, k_up, k_down = jax.random.split(key, 3)
-    up0, down0 = bandwidth_draw(k_static, n_sites, lo, hi)
-    u = 1.0 + wobble * (2.0 * jax.random.uniform(k_up, (t_slots, n_sites)) - 1.0)
-    d = 1.0 + wobble * (2.0 * jax.random.uniform(k_down, (t_slots, n_sites)) - 1.0)
-    return up0[None, :] * u, down0[None, :] * d
-
-
 def link_fault_trace(
     key: Array,
     t_slots: int,

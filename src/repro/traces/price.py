@@ -95,13 +95,3 @@ def price_trace(
 
     trace = base[None, :] * weekly + amp[None, :] * diurnal + noise
     return jnp.maximum(trace, 1.0)  # prices stay positive
-
-
-def load_price_csv(path: str, n_sites: int) -> Array:
-    """Load a real (T, N) price trace from CSV (slot rows × site columns)."""
-    data = np.loadtxt(path, delimiter=",", dtype=np.float32)
-    if data.ndim == 1:
-        data = data[:, None]
-    if data.shape[1] != n_sites:
-        raise ValueError(f"expected {n_sites} columns, got {data.shape[1]}")
-    return jnp.asarray(data)

@@ -38,13 +38,3 @@ def pue_trace(
     noise = 0.004 * jax.random.normal(key, (t_slots, len(sites)))
     trace = base[None, :] + amp[None, :] * diurnal + noise
     return jnp.maximum(trace, 1.0)  # PUE >= 1 by definition
-
-
-def load_pue_csv(path: str, n_sites: int) -> Array:
-    """Load a real (T, N) PUE trace from CSV."""
-    data = np.loadtxt(path, delimiter=",", dtype=np.float32)
-    if data.ndim == 1:
-        data = data[:, None]
-    if data.shape[1] != n_sites:
-        raise ValueError(f"expected {n_sites} columns, got {data.shape[1]}")
-    return jnp.asarray(data)

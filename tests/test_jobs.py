@@ -17,6 +17,7 @@ from repro.core.baselines import (
     random_dispatch,
 )
 from repro.core.gmsa import dispatch_fn, gmsa_policy
+from repro.core.policy import declare
 from repro.core.simulator import simulate
 from repro.jobs import (
     chain_dag,
@@ -295,8 +296,7 @@ def test_returns_flow_export_matches_engine_recursion(staged_setup):
     def stripped(key, q, arrivals, mu, e, aux, scalar):
         return aware(key, q, arrivals, mu, e, aux, scalar)[0]
 
-    stripped.staged = True
-    stripped.consumes_key = False
+    declare(stripped, keyed=False, staged=True, reads="wpue")
     key = jax.random.key(4)
     o_exp = simulate_staged(template, dag, wan, aware, key, scalar=cfg.v)
     o_rec = simulate_staged(template, dag, wan, stripped, key, scalar=cfg.v)
